@@ -3,11 +3,11 @@ legacy `jnp.repeat` expansion, across serving-shaped (decode / prefill)
 cases.
 
 Times the blockwise jnp path (what model lowering uses on CPU) against a
-full-softmax reference on small shapes, and runs Pallas interpret-mode
-probes — including a **traced-offset decode** probe (q_offset as a jitted
-scalar operand, the case that used to fall back to blockwise) — as
-correctness checks.  Emits ``BENCH_attention.json`` at the repo root via
-`benchmarks/common.py`.
+full-softmax reference on small shapes, and runs Pallas probes
+(interpret mode off-TPU) — including a **traced-offset decode** probe
+(q_offset as a jitted scalar operand, the case that used to fall back to
+blockwise) — as correctness checks.  Emits ``BENCH_attention.json`` at the
+repo root via `benchmarks/common.py`.
 
 Timing hygiene matches `conv_kernels.py`: jitted entry points hoisted to
 module level, compile reported separately from the steady-state mean.
@@ -60,7 +60,7 @@ def _attn_decode_traced(q, k, v, q_offset):
     # q_offset is a traced scalar: exercises the scalar-prefetch decode
     # path of the Pallas kernel (previously a blockwise fallback).
     return ops.attention(q, k, v, causal=True, q_offset=q_offset,
-                         impl="pallas", interpret=True)
+                         impl="pallas", interpret=None)
 
 
 def _bench(fn, *args, reps: int = 5, **kw):
@@ -109,17 +109,17 @@ def run() -> dict:
             "ok": traffic_ok,
         })
 
-    # Pallas interpret probes (correctness, not speed): native GQA kernel
+    # Pallas probes (correctness, not speed): native GQA kernel
     # ≡ blockwise ≡ ref on a small GQA shape, plus traced-offset decode.
     B, T, H, Hkv, D = 1, 64, 8, 2, 16
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, T, Hkv, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, T, Hkv, D)), jnp.float32)
     want = ref_attention(q, k, v, causal=True)
-    c_us, s_us = _bench(_attn, q, k, v, impl="pallas", interpret=True,
+    c_us, s_us = _bench(_attn, q, k, v, impl="pallas", interpret=None,
                         reps=3)
     d_full = float(jnp.max(jnp.abs(
-        _attn(q, k, v, impl="pallas", interpret=True) - want)))
+        _attn(q, k, v, impl="pallas", interpret=None) - want)))
     dec = _attn_decode_traced(q[:, -1:], k, v, jnp.asarray(T - 1, jnp.int32))
     d_dec = float(jnp.max(jnp.abs(dec[:, 0] - want[:, -1])))
     probes = {"pallas_gqa": {"compile_us": round(c_us, 1),
@@ -132,7 +132,8 @@ def run() -> dict:
             "bytes_native", "native_traffic_win_x", "ok"]
     print(fmt_table(rows, cols))
     for name, p in probes.items():
-        print(f"{name}(interpret) probe: |Δ vs ref| = {p['maxdiff']:.2e} "
+        print(f"{name} probe ({jax.default_backend()}): |Δ vs ref| = "
+              f"{p['maxdiff']:.2e} "
               f"({'OK' if p['maxdiff'] < 1e-3 else 'FAIL'})")
     min_win = min(r["native_traffic_win_x"] for r in rows if r["rep"] >= 4)
     out = {"rows": rows, "probes": probes,

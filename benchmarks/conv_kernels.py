@@ -1,9 +1,9 @@
 """Log-conv kernel timings across the paper's CNN layer shapes.
 
 Times `kernels/ops.conv2d` (blockwise jnp path, plus fused and im2col
-Pallas probes in interpret mode on a small layer as correctness checks)
-against the fp32 `lax.conv` baseline, on VGG-16 / MobileNet-v1 layer
-shapes from `core/accelerator.py` scaled to a CI-sized image.  Emits
+Pallas probes on a small layer as correctness checks, in interpret mode
+off-TPU) against the fp32 `lax.conv` baseline, on VGG-16 / MobileNet-v1
+layer shapes from `core/accelerator.py` scaled to a CI-sized image.  Emits
 ``BENCH_conv.json`` at the repo root via `benchmarks/common.py` (which
 also prints a delta table against the previous run).
 
@@ -211,12 +211,12 @@ def run() -> dict:
             "ok": row_ok,
         })
 
-    # Pallas interpret probes on a small layer (correctness, not speed):
+    # Pallas probes on a small layer (correctness, not speed):
     # fused ≡ im2col ≡ blockwise, compile and steady time reported apart
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 3)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(3, 3, 3, 16)).astype(np.float32))
     qt = quantize_tensor(w)
-    pkw = dict(stride=1, padding="SAME", groups=1, interpret=True)
+    pkw = dict(stride=1, padding="SAME", groups=1, interpret=None)
     probes = {}
     y_bw = _logq_conv(x, qt, impl="blockwise", stride=1, padding="SAME",
                       groups=1)
@@ -257,7 +257,7 @@ def run() -> dict:
         win = padded["act_w"] / packed["act_w"]
         y_ref = _logq_conv(xg, qtg, impl="blockwise", **gkw)
         d = float(jnp.max(jnp.abs(
-            _logq_conv(xg, qtg, impl="pallas", interpret=True, **gkw)
+            _logq_conv(xg, qtg, impl="pallas", interpret=None, **gkw)
             - y_ref)))
         cin_g = C // G
         row_ok = (d < 1e-3) and (win >= LANE_PACK_WIN if cin_g <= 4
@@ -289,7 +289,8 @@ def run() -> dict:
                                 "lane_pack_win_x", "lane_density_packed",
                                 "ok"]))
     for impl, p in probes.items():
-        print(f"{impl}(interpret) probe: compile {p['compile_us']:.0f} µs, "
+        print(f"{impl} probe ({jax.default_backend()}): "
+              f"compile {p['compile_us']:.0f} µs, "
               f"steady {p['steady_us']:.0f} µs, |Δ vs blockwise| = "
               f"{p['maxdiff']:.2e} ({'OK' if p['maxdiff'] < 1e-3 else 'FAIL'})")
     print(f"cold_start: {cold['conv_dispatches']} conv dispatches over "

@@ -11,6 +11,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.runtime.compile_cache import setup_compile_cache
+
 from . import (ablation_grad_compress, attention_kernels, conv_kernels,
                fig1_quant, fig17_pe_cost, fig19_utilization, fig20_throughput,
                table2_comparison, table3_latency, telemetry_overhead)
@@ -40,6 +42,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", nargs="*",
                     choices=list(BENCHES) + list(ALIASES))
     args = ap.parse_args(argv)
+    setup_compile_cache()
     names = [ALIASES.get(n, n) for n in (args.only or list(BENCHES))]
 
     summary = []

@@ -57,7 +57,7 @@ def serve_packed(params, apply_fn, batch):
     from repro.serving.quantize import quantize_cnn_params, quantized_fraction
 
     qparams = quantize_cnn_params(params)
-    apply_q = functools.partial(apply_fn, conv_impl="blockwise")
+    apply_q = functools.partial(apply_fn, conv_impl="auto")  # pallas on TPU
     logits_fq = apply_fn(params, batch["images"])
     logits_q = apply_q(qparams, batch["images"])
     acc = float(jnp.mean(jnp.argmax(logits_q, -1) == batch["labels"]))
@@ -92,7 +92,7 @@ def main():
               f"(vs {perf.ddr_bytes_fp16/2**20:.1f} MiB fp16 — "
               f"{perf.ddr_bytes_fp16/perf.ddr_bytes_log:.2f}× saved)")
     print("\nThe log codes cut DDR traffic ≈2.3× — on TPU the same codes cut "
-          "HBM weight traffic 2.67× vs bf16 (see EXPERIMENTS.md §Perf).")
+          "HBM weight traffic 2.67× vs bf16.")
 
 
 if __name__ == "__main__":

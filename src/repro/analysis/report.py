@@ -20,7 +20,7 @@ from collections import defaultdict
 
 from ..configs.base import SHAPES
 from ..configs.registry import ARCH_NAMES
-from .roofline import HBM_BW, from_record, load_records
+from .roofline import device_peaks, from_record, load_records
 
 
 def dryrun_table(recs: list[dict]) -> str:
@@ -111,8 +111,14 @@ def _fmt_bytes(n) -> str:
 def per_op_utilization_table(snap: dict) -> str:
     """Per-dispatch utilization rows from a telemetry snapshot: analytic
     bytes moved (the paper's traffic accounting) over measured steady time
-    → achieved GB/s, as a fraction of the HBM roofline."""
-    recs = snap.get("kernels", snap).get("records", [])
+    → achieved GB/s, as a fraction of the HBM roofline of the snapshot's
+    device (`roofline.device_peaks`; a TPU missing from the peaks table
+    raises, a CPU snapshot has no roofline column)."""
+    kern = snap.get("kernels", snap)
+    recs = kern.get("records", [])
+    dev = kern["device"]
+    hbm_bw = (device_peaks(dev["kind"])["hbm_bytes_per_s"]
+              if dev["platform"] == "tpu" else None)
     lines = ["| op | impl | shape key | calls | bytes moved | steady µs | "
              "GB/s | %HBM roofline | timing |",
              "|---|---|---|---|---|---|---|---|---|"]
@@ -122,7 +128,8 @@ def per_op_utilization_table(snap: dict) -> str:
         us = r.get("steady_us")
         if us:
             gbps = total / (us * 1e-6) / 1e9
-            util = f"{100 * gbps * 1e9 / HBM_BW:.2f}%"
+            util = (f"{100 * gbps * 1e9 / hbm_bw:.2f}%" if hbm_bw
+                    else f"— ({dev['platform']})")
             us_s, gb_s = f"{us:.1f}", f"{gbps:.3f}"
         else:
             us_s, gb_s, util = "—", "—", "—"

@@ -15,8 +15,9 @@ parses the post-SPMD optimized HLO (``compiled.as_text()``, where partitioner
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
 Shapes in that text are already per-device.
 
-Hardware model: TPU v5e-like — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (per assignment).
+Hardware model: per-chip peaks come from `PEAKS`, keyed by the JAX
+``device_kind`` of the chip the numbers are for; the dry-run models a
+TPU v5e (`DRYRUN_DEVICE_KIND`) with ~50 GB/s/link ICI (per assignment).
 """
 
 from __future__ import annotations
@@ -27,9 +28,26 @@ import os
 import re
 from collections import defaultdict
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published per-chip peaks, keyed by JAX's `device_kind`.  Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB
+# HBM at 819 GB/s).  A chip missing here has no roofline: add it with its
+# source rather than borrowing another chip's numbers.
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9},
+}
+
+DRYRUN_DEVICE_KIND = "TPU v5 lite"  # the chip the dry-run's meshes model
+ICI_BW = 50e9                       # bytes/s per link
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip; raises for a `device_kind` not in `PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -115,6 +133,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str
     flops_per_dev: float          # unroll-diff-corrected, per device
     bytes_per_dev: float
     coll_bytes_per_dev: float
@@ -123,11 +142,13 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_dev / PEAK_FLOPS
+        return (self.flops_per_dev
+                / device_peaks(self.device_kind)["bf16_flops"])
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_dev / HBM_BW
+        return (self.bytes_per_dev
+                / device_peaks(self.device_kind)["hbm_bytes_per_s"])
 
     @property
     def t_collective(self) -> float:
@@ -154,7 +175,8 @@ class Roofline:
     def mfu(self) -> float:
         """Model-FLOPs utilisation at the roofline-bound step time."""
         t = self.step_time
-        return (self.model_flops / (self.chips * PEAK_FLOPS)) / t if t else 0.0
+        peak = self.chips * device_peaks(self.device_kind)["bf16_flops"]
+        return (self.model_flops / peak) / t if t else 0.0
 
     def row(self) -> dict:
         return {
@@ -186,7 +208,7 @@ def from_record(rec: dict) -> Roofline:
     """Build a Roofline from one dry-run JSON record."""
     return Roofline(
         arch=rec["arch"], shape=rec["shape"], mesh=rec["mesh"],
-        chips=rec["chips"],
+        chips=rec["chips"], device_kind=rec["device_kind"],
         flops_per_dev=rec["cost_true"]["flops"],
         bytes_per_dev=rec["cost_true"]["bytes"],
         coll_bytes_per_dev=rec["cost_true"]["collective_bytes"],

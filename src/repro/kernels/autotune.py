@@ -55,15 +55,13 @@ import jax
 from repro.core.logquant import LogQuantConfig
 from repro.obs import metrics as _obs_metrics
 from .flash_attention import flash_attention_pallas
-from .log_conv2d import (fused_conv_geometry, lane_pack_geometry,
-                         log_conv2d_fused_pallas, normalize_padding)
+from .log_conv2d import (VMEM_BUDGET_BYTES, fused_conv_geometry,
+                         lane_pack_geometry, log_conv2d_fused_pallas,
+                         normalize_padding)
 
 # v3: conv config space gained `lane_pack` (grouped-conv lane packing);
 # v2: op-namespaced keys (conv2d|… / attention|…), one table for all ops
 SCHEMA_VERSION = 3
-
-# VMEM high-water mark a candidate launch may plan for (double-buffered)
-VMEM_BUDGET_BYTES = 8 << 20
 
 _CACHE: dict | None = None  # lazy-loaded user tier, reset via reset_cache()
 _PACKAGED: dict[str, dict] | None = None  # backend → packaged-tier entries
@@ -206,26 +204,29 @@ def record(key: str, config: dict, us: float) -> None:
 
 def estimate_vmem_bytes(B, H, W, C, K, Cout, *, stride=1, padding="SAME",
                         groups=1, **config) -> int:
-    """Planned VMEM per grid step: activation slab + weight block + psum
-    accumulator + out block, ×2 for double buffering of the streamed refs."""
-    g = fused_conv_geometry(B, H, W, C, K, Cout, stride=stride,
-                            padding=padding, groups=groups, **config)
-    slab = g["bt"] * g["rows_in"] * g["Wp"] * g["bcin"] * 4
-    wblk = g["bcin"] * g["bcout"]
-    acc = g["bt"] * g["rt"] * g["Wo"] * g["ow"] * 4
-    # lane packing expands the decoded weight block to [Lc, bcout*g_b] f32
-    # in VMEM before the dot (compact codes stay int8 in the stream)
-    wexp = g["bcin"] * g["ow"] * 4 if g["g_b"] > 1 else 0
-    return 2 * (slab + wblk) + 2 * acc + wexp
+    """Planned VMEM per grid step, padded to the chip's (8, 128) tiles and
+    double-buffered (see `log_conv2d._fused_vmem_bytes`)."""
+    return fused_conv_geometry(B, H, W, C, K, Cout, stride=stride,
+                               padding=padding, groups=groups,
+                               **config)["vmem"]
 
 
 def default_config(B, H, W, C, K, Cout, *, stride=1, padding="SAME",
                    groups=1) -> dict:
-    """Heuristic used on a tuning-table miss: MXU-sized channel blocks, one
-    row tile (zero halo duplication), batch tile as wide as VMEM allows,
-    lane packing on auto (engages whenever g_b ≥ 2 groups fit a lane block)."""
-    return dict(block_cin=128, block_cout=128, rows_per_tile=None,
-                batch_per_tile=None, lane_pack=None)
+    """Heuristic used on a tuning-table miss: MXU-sized channel blocks, the
+    fewest row tiles whose grid step fits `VMEM_BUDGET_BYTES`, the batch
+    tile then as wide as the budget allows, lane packing on auto (engages
+    whenever g_b ≥ 2 groups fit a lane block)."""
+    cfg = dict(block_cin=128, block_cout=128, rows_per_tile=None,
+               batch_per_tile=None, lane_pack=None)
+    kw = dict(stride=stride, padding=padding, groups=groups)
+    Ho = fused_conv_geometry(B, H, W, C, K, Cout, **kw)["Ho"]
+    for n_rt in range(1, Ho + 1):
+        rt = -(-Ho // n_rt)
+        if estimate_vmem_bytes(B, H, W, C, K, Cout, **kw, **dict(
+                cfg, rows_per_tile=rt)) <= VMEM_BUDGET_BYTES:
+            break
+    return dict(cfg, rows_per_tile=rt)
 
 
 def candidate_configs(B, H, W, C, K, Cout, *, stride=1, padding="SAME",

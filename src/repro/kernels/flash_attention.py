@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -145,7 +144,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows + pq, D), q.dtype),
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(offs, qp, kp, vp)

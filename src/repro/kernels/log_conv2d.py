@@ -12,14 +12,15 @@ dispatch layer):
 
   * ``log_conv2d_fused_pallas`` — direct NHWC conv: patch extraction
     happens *in VMEM* (implicit im2col).  The grid walks (batch·row tiles,
-    groups, output-channel tiles, reduction over Cin blocks × K² taps);
-    an activation slab is loaded once per tile and re-sliced for every tap
-    (line-buffer-style reuse of the paper's §5 weight broadcast — no K²×
-    patch blow-up in HBM), weight codes stay packed int8 in HBM and decode
-    next to the MXU (eq. 8's LUT+shift as `exp2` of a half-integer), and
-    psums stay in the VMEM accumulator until flush.  Grouped/depthwise
-    convs are a grid dimension over groups — each step contracts only its
-    group's `cin_g` slice, so no block-diagonal `groups`× byte/FLOP waste.
+    groups, output-channel tiles, reduction over Cin blocks), each step
+    looping over the K² taps; an activation slab is loaded once per tile
+    and re-sliced for every tap (line-buffer-style reuse of the paper's §5
+    weight broadcast — no K²× patch blow-up in HBM), weight codes stay
+    packed int8 in HBM and decode next to the MXU (eq. 8's LUT+shift as
+    `exp2` of a half-integer), and psums stay in the VMEM accumulator
+    until flush.  Grouped/depthwise convs are a grid dimension over
+    groups — each step contracts only its group's `cin_g` slice, so no
+    block-diagonal `groups`× byte/FLOP waste.
     Block sizes (`block_cin/block_cout/rows_per_tile/batch_per_tile`) are
     tunable; `kernels/autotune.py` measures and persists winners.
   * ``log_conv2d_pallas`` — the explicit-im2col fallback: patches are
@@ -68,7 +69,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.logquant import LogQuantConfig, log_dequantize
-from ._compat import TPUCompilerParams
 from .log_matmul import _decode_block, log_matmul_pallas
 from .ref import ref_log_matmul
 
@@ -286,6 +286,40 @@ def _fit_dim(x, axis: int, size: int):
     return x
 
 
+SUBLANES = 8  # 32-bit rows per VMEM tile (int8 packs 4× as many)
+
+# VMEM one fused-conv grid step may plan for: half of a v5e's 16 MiB scoped
+# default, leaving the rest to Mosaic's own scratch
+VMEM_BUDGET_BYTES = 8 << 20
+
+
+def _vmem_tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """Bytes a [rows, cols] array takes in VMEM: the last dim padded to 128
+    lanes, the second-last to whole sublane tiles."""
+    return (_ceil_to(rows, SUBLANES * 4 // itemsize) * _ceil_to(cols, LANES)
+            * itemsize)
+
+
+def _fused_vmem_bytes(*, bt, rt, rows_in, Wp, Wo, bcin, bcout, ow, taps,
+                      stride, g_b) -> int:
+    """VMEM one grid step of the fused kernel uses, laid out as the chip
+    lays it out (f32 activations, int8 codes): the double-buffered
+    activation slab, code, scale and output blocks, the psum scratch, and
+    the values one tap keeps live — its strided slab slice, the patch, the
+    decoded (lane-packed: mask-expanded) weight block and the dot result."""
+    M = bt * rt * Wo
+    streamed = (bt * rows_in * _vmem_tile_bytes(Wp, bcin)
+                + taps * _vmem_tile_bytes(bcin, bcout, 1)
+                + _vmem_tile_bytes(1, ow)
+                + bt * rt * _vmem_tile_bytes(Wo, ow))
+    acc = _vmem_tile_bytes(M, ow)
+    tap = (bt * rt * stride * _vmem_tile_bytes(Wo * stride, bcin)
+           + _vmem_tile_bytes(M, bcin) + _vmem_tile_bytes(bcin, ow) + acc)
+    if g_b > 1:
+        tap += bcin * _vmem_tile_bytes(bcout, g_b)
+    return 2 * streamed + acc + tap
+
+
 def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
                         *, stride: int = 1, padding="SAME", groups: int = 1,
                         block_cin: int = 128, block_cout: int = 128,
@@ -296,6 +330,9 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
 
     Shared by the kernel itself, the autotuner's VMEM filter, and the
     analytic traffic model, so all three describe the same launch.
+    ``vmem`` is the step's planned VMEM as the chip lays it out
+    (`_fused_vmem_bytes`); a ``batch_per_tile=None`` launch takes the
+    widest batch tile that keeps it within `VMEM_BUDGET_BYTES`.
 
     When lane packing engages (``g_b > 1``), the channel axis is tiled by
     superblocks of ``g_b`` groups: ``bcin`` becomes the packed lane width
@@ -323,59 +360,68 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
     Wp = Wo * stride + K - 1
     Hp = n_rt * rt * stride + K - 1        # rows so every tile's halo exists
     BT = B * n_rt
+    vmem = functools.partial(
+        _fused_vmem_bytes, rt=rt, rows_in=rows_in, Wp=Wp, Wo=Wo, bcin=bcin,
+        bcout=bcout, ow=bcout * g_b, taps=K * K, stride=stride, g_b=g_b)
     if batch_per_tile is None:
         # weight-stationary across batch (the paper's multi-threaded weight
-        # broadcast): widen the batch tile while the slab fits ~4 MB VMEM
-        per = max(rows_in * Wp * bcin * 4, 1)
-        bt = max(1, min(BT, (4 << 20) // per))
+        # broadcast): the widest batch tile whose step fits the VMEM budget
+        bt = max((d for d in range(1, BT + 1)
+                  if BT % d == 0 and vmem(bt=d) <= VMEM_BUDGET_BYTES),
+                 default=1)
     else:
         bt = max(1, min(int(batch_per_tile), BT))
-    while BT % bt:
-        bt -= 1
+        while BT % bt:
+            bt -= 1
     return dict(pads=pads, Ho=Ho, Wo=Wo, cin_g=cin_g, cout_g=cout_g,
                 rt=rt, n_rt=n_rt, bcin=bcin, bcout=bcout, cin_gp=cin_gp,
                 cout_gp=cout_gp, rows_in=rows_in, Wp=Wp, Hp=Hp, BT=BT, bt=bt,
                 ncb=cin_gp // bcin, njb=cout_gp // bcout, taps=K * K,
-                g_b=g_b, cin_lane=cin_lane, n_sb=n_sb, ow=bcout * g_b)
+                g_b=g_b, cin_lane=cin_lane, n_sb=n_sb, ow=bcout * g_b,
+                vmem=vmem(bt=bt))
 
 
 def _fused_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
                   cfg: LogQuantConfig, K: int, stride: int, bt: int, rt: int,
                   Wo: int, acc_dtype, g_b: int = 1, cin_lane: int = 0):
-    c, t = pl.program_id(3), pl.program_id(4)
+    c = pl.program_id(3)
 
-    @pl.when((c == 0) & (t == 0))
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # implicit im2col: slice tap (kh, kw) out of the VMEM-resident activation
-    # slab — the slab itself was fetched once for this (tile, cin-block) and
-    # is re-sliced for all K² taps (line-buffer reuse, no HBM patch blow-up).
-    kh, kw = t // K, t % K
+    # implicit im2col: slice every tap (kh, kw) out of the VMEM-resident
+    # activation slab — the slab was fetched once for this (tile, cin-block)
+    # and is re-sliced for all K² taps (line-buffer reuse, no HBM patch
+    # blow-up).  The taps are a static loop: Mosaic only accepts a W
+    # (sublane) offset it can prove 8-aligned when the offset is dynamic.
     SH, SW = rt * stride, Wo * stride
-    xs = x_ref[:, pl.ds(kh, SH), pl.ds(kw, SW), :]       # [bt, SH, SW, bcin]
-    if stride > 1:
-        xs = xs.reshape(bt, rt, stride, Wo, stride, -1)[:, :, 0, :, 0, :]
-    patch = xs.reshape(bt * rt * Wo, -1).astype(acc_dtype)
+    for t in range(K * K):
+        kh, kw = divmod(t, K)
+        xs = x_ref[:, kh:kh + SH, kw:kw + SW, :]         # [bt, SH, SW, bcin]
+        if stride > 1:
+            xs = xs.reshape(bt, rt, stride, Wo, stride, -1)[:, :, 0, :, 0, :]
+        patch = xs.reshape(bt * rt * Wo, -1).astype(acc_dtype)
 
-    # decode this tap's weight block next to the MXU (eq. 8 LUT+shift)
-    w = _decode_block(w_ref[0, 0], cfg, acc_dtype)       # [bcin, bcout]
-    if g_b > 1:
-        # unpack the group-to-lane map next to the MXU: the compact block
-        # serves g_b groups at once; lane l belongs to group l//cin_lane,
-        # so output column (o, g) is masked to exactly its group's lanes
-        # (out-of-group taps contribute an exact 0 to the contraction).
-        Lc, bcout = w.shape
-        lane_g = jax.lax.broadcasted_iota(jnp.int32, (Lc, g_b), 0) // cin_lane
-        col_g = jax.lax.broadcasted_iota(jnp.int32, (Lc, g_b), 1)
-        mask = (lane_g == col_g).astype(acc_dtype)       # [Lc, g_b]
-        w = (w[:, :, None] * mask[:, None, :]).reshape(Lc, bcout * g_b)
-    acc_ref[...] += jnp.dot(patch, w, preferred_element_type=acc_dtype)
+        # decode this tap's weight block next to the MXU (eq. 8 LUT+shift)
+        w = _decode_block(w_ref[0, t], cfg, acc_dtype)   # [bcin, bcout]
+        if g_b > 1:
+            # unpack the group-to-lane map next to the MXU: the compact
+            # block serves g_b groups at once; lane l belongs to group
+            # l//cin_lane, so output column (o, g) is masked to exactly its
+            # group's lanes (out-of-group taps contribute an exact 0).
+            Lc, bcout = w.shape
+            lane_g = jax.lax.broadcasted_iota(jnp.int32, (Lc, g_b),
+                                              0) // cin_lane
+            col_g = jax.lax.broadcasted_iota(jnp.int32, (Lc, g_b), 1)
+            mask = (lane_g == col_g).astype(acc_dtype)   # [Lc, g_b]
+            w = (w[:, :, None] * mask[:, None, :]).reshape(Lc, bcout * g_b)
+        acc_ref[...] += jnp.dot(patch, w, preferred_element_type=acc_dtype)
 
-    @pl.when((c == pl.num_programs(3) - 1) & (t == pl.num_programs(4) - 1))
+    @pl.when(c == pl.num_programs(3) - 1)
     def _flush():
         out = acc_ref[...] * s_ref[0].astype(acc_dtype)
-        o_ref[...] = out.reshape(bt, rt, Wo, 1, -1).astype(o_ref.dtype)
+        o_ref[...] = out.reshape(bt, rt, Wo, -1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -393,13 +439,14 @@ def log_conv2d_fused_pallas(x, packed, scale,
                             prepacked: bool = False):
     """Direct NHWC conv with VMEM patch extraction (implicit im2col).
 
-    Grid: (batch·row tiles, group superblocks, cout blocks, cin blocks,
-    K² taps) with the reduction (cin, tap) innermost — the activation
-    slab's block index is constant across all taps, so it is fetched once
-    per tile and reused K² times; weight codes stream as packed int8 and
-    decode in VMEM; psums live in a VMEM scratch until the last reduction
-    step.  Groups are a grid dimension: each step contracts only its
-    group's `cin_g` slice.  Block sizes are the autotuner's knobs.
+    Grid: (batch·row tiles, group superblocks, cout blocks, cin blocks)
+    with the cin reduction innermost; each step loops over all K² taps of
+    its activation slab, so the slab is fetched once per (tile, cin block)
+    and reused K² times; weight codes stream as packed int8 (all taps of a
+    block at once) and decode in VMEM; psums live in a VMEM scratch until
+    the last reduction step.  Groups are a grid dimension: each step
+    contracts only its group's `cin_g` slice.  Block sizes are the
+    autotuner's knobs.
 
     ``lane_pack`` (see `lane_pack_geometry`) packs ``g_b`` narrow groups
     into one 128-lane channel block: the groups grid dimension collapses
@@ -485,30 +532,30 @@ def log_conv2d_fused_pallas(x, packed, scale,
     s = jnp.pad(s.reshape(G, cout_g), ((0, n_sb * g_b - G),
                                        (0, cout_gp - cout_g)))
     s = s.reshape(n_sb, g_b, cout_gp).transpose(0, 2, 1)
-    s = s.reshape(n_sb, cout_gp * g_b)
+    s = s.reshape(n_sb, 1, cout_gp * g_b)
 
     acc_dtype = jnp.float32
     out = pl.pallas_call(
         functools.partial(_fused_kernel, cfg=cfg, K=K, stride=stride, bt=bt,
                           rt=rt, Wo=Wo, acc_dtype=acc_dtype, g_b=g_b,
                           cin_lane=cin_lane),
-        grid=(BT // bt, n_sb, njb, ncb, taps),
+        grid=(BT // bt, n_sb, njb, ncb),
         in_specs=[
             pl.BlockSpec((bt, rows_in, Wp, bcin),
-                         lambda bi, gg, j, c, t: (bi, 0, 0, gg * ncb + c)),
-            pl.BlockSpec((1, 1, bcin, bcout),
-                         lambda bi, gg, j, c, t: (gg, t, c, j)),
-            pl.BlockSpec((1, ow), lambda bi, gg, j, c, t: (gg, j)),
+                         lambda bi, gg, j, c: (bi, 0, 0, gg * ncb + c)),
+            pl.BlockSpec((1, taps, bcin, bcout),
+                         lambda bi, gg, j, c: (gg, 0, c, j)),
+            pl.BlockSpec((1, 1, ow), lambda bi, gg, j, c: (gg, 0, j)),
         ],
-        out_specs=pl.BlockSpec((bt, rt, Wo, 1, ow),
-                               lambda bi, gg, j, c, t: (bi, 0, 0, gg, j)),
-        out_shape=jax.ShapeDtypeStruct((BT, rt, Wo, n_sb, cout_gp * g_b),
+        out_specs=pl.BlockSpec((bt, rt, Wo, ow),
+                               lambda bi, gg, j, c: (bi, 0, 0, gg * njb + j)),
+        out_shape=jax.ShapeDtypeStruct((BT, rt, Wo, n_sb * cout_gp * g_b),
                                        out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bt * rt * Wo, ow), acc_dtype)],
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+                                 "arbitrary")),
     )(xrt, w, s)
     # unscramble: [.., n_sb, (o, g)] → group-major channels, crop padding
     out = out.reshape(B, n_rt * rt, Wo, n_sb, cout_gp, g_b)[:, :Ho]

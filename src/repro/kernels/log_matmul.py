@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.logquant import LogQuantConfig
-from ._compat import TPUCompilerParams
 
 DEFAULT_CFG = LogQuantConfig()
 
@@ -104,7 +103,7 @@ def log_matmul_pallas(x, packed, scale, cfg: LogQuantConfig = DEFAULT_CFG,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), acc_dtype)],
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(xp, wp, sp)
     return out[:M, :N]

@@ -27,30 +27,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import TPUCompilerParams
-
 
 def _chunk_math(r, k, v, logw, u, S0):
     """One chunk of the closed form above.  All inputs fp32.
 
-    r, k, logw: [L, K]; v: [L, V]; u: [K]; S0: [K, V] → (o [L, V], S_L)."""
+    r, k, logw: [L, K]; v: [L, V]; u: [K] or [1, K]; S0: [K, V]
+    → (o [L, V], S_L)."""
     L = r.shape[0]
-    p = jnp.cumsum(logw, axis=0)                       # P̃_t, [L, K]
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # P̃_t as a lower-triangular matmul: Pallas TPU has no cumsum
+    p = jnp.dot((ii >= jj).astype(f32), logw, precision=hi,
+                preferred_element_type=f32)            # P̃_t, [L, K]
     p_prev = p - logw                                  # P̃_{t-1}
     q_t = r * jnp.exp(p_prev)
     k_t = k * jnp.exp(-p)
     a = jax.lax.dot_general(q_t, k_t, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [L, L]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+                            preferred_element_type=f32)  # [L, L]
     a = jnp.where(ii > jj, a, 0.0)                     # strict causal
-    o = jnp.dot(a, v, preferred_element_type=jnp.float32)
-    o += jnp.sum(r * (u[None] * k), axis=1, keepdims=True) * v
-    o += jnp.dot(q_t, S0, preferred_element_type=jnp.float32)
-    pL = p[-1]
-    k_hat = k * jnp.exp(pL[None] - p)
-    S = jnp.exp(pL)[:, None] * S0 + jax.lax.dot_general(
-        k_hat, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    o = jnp.dot(a, v, preferred_element_type=f32)
+    o += jnp.sum(r * (u.reshape(1, -1) * k), axis=1, keepdims=True) * v
+    o += jnp.dot(q_t, S0, preferred_element_type=f32)
+    pL = p[L - 1:]                                     # P̃_L, [1, K]
+    k_hat = k * jnp.exp(pL - p)
+    # exp(P̃_L) as a [K, 1] column, by a dot that contracts the unit dims
+    decay = jax.lax.dot_general(jnp.exp(pL), jnp.ones((1, 1), f32),
+                                (((0,), (0,)), ((), ())), precision=hi,
+                                preferred_element_type=f32)
+    S = decay * S0 + jax.lax.dot_general(
+        k_hat, v, (((0,), (0,)), ((), ())), preferred_element_type=f32)
     return o, S
 
 
@@ -96,7 +102,9 @@ def wkv6_pallas(r, k, v, logw, u, state=None, *, chunk=64, interpret=False):
         return a.transpose(0, 2, 1, 3).reshape(B * H, Tp, -1)
 
     rp, kp, vp, wp = bh(rp), bh(kp), bh(vp), bh(wp)
-    up = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, K)
+    # u rides as [B·H, 1, K] so its (1, K) block spans the array's last two
+    # dims, as the TPU's (8, 128) block rule requires
+    up = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, 1, K)
     sp = state.reshape(B * H, K, V)
 
     o, sT = pl.pallas_call(
@@ -107,7 +115,7 @@ def wkv6_pallas(r, k, v, logw, u, state=None, *, chunk=64, interpret=False):
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, V), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, K), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, K, V), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
@@ -120,7 +128,7 @@ def wkv6_pallas(r, k, v, logw, u, state=None, *, chunk=64, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(rp, kp, vp, wp, up, sp)
 

@@ -36,7 +36,8 @@ import traceback
 
 import jax
 
-from ..analysis.roofline import collective_bytes, model_flops_for
+from ..analysis.roofline import (DRYRUN_DEVICE_KIND, collective_bytes,
+                                 model_flops_for)
 from ..configs.base import SHAPES, cell_is_runnable
 from ..configs.registry import ARCH_NAMES, get_config
 from .mesh import make_production_mesh
@@ -119,6 +120,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     sh = SHAPES[shape_name]
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name,
            "chips": 512 if multi_pod else 256,
+           "device_kind": DRYRUN_DEVICE_KIND,
            "model_flops": model_flops_for(cfg, sh),
            "params": cfg.param_count(),
            "active_params": cfg.active_param_count()}

@@ -9,20 +9,23 @@ and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple):
+    """Arbitrary mesh (elastic restarts onto shrunken worlds).  Axes are
+    Auto: the models place values with `with_sharding_constraint`, which
+    an Explicit axis (`jax.make_mesh`'s default) refuses."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh (elastic restarts onto shrunken worlds)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has — for examples/ and smoke runs."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, len(jax.devices())), ("data", "model"))

@@ -21,8 +21,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from ..analysis.roofline import (Roofline, collective_bytes,
-                                 model_flops_for)
+from ..analysis.roofline import (DRYRUN_DEVICE_KIND, Roofline,
+                                 collective_bytes, model_flops_for)
 from ..configs.base import SHAPES
 from ..configs.registry import ARCH_NAMES, get_config
 from .dryrun import _compile, _main_seg_reps, _memory, _plain_cost
@@ -138,6 +138,7 @@ def run_variant(arch: str, shape_name: str, variant: str, *,
                     for t in set(collA["by_type"]) | set(collB["by_type"])}
 
     r = Roofline(arch=arch, shape=shape_name, mesh="single", chips=256,
+                 device_kind=DRYRUN_DEVICE_KIND,
                  flops_per_dev=true["flops"], bytes_per_dev=true["bytes"],
                  coll_bytes_per_dev=true["collective_bytes"],
                  model_flops=model_flops_for(cfg, sh),

@@ -7,17 +7,17 @@
 from __future__ import annotations
 
 import argparse
+import json
+import os
 import time
 
 import jax
 import numpy as np
 
-import json
-import os
-
 from ..configs.registry import ARCH_NAMES, get_config
 from ..models import sharding, transformer
 from ..obs import trace as obs_trace
+from ..runtime.compile_cache import setup_compile_cache
 from ..serving.engine import EngineConfig, Request, ServeEngine
 from .mesh import make_host_mesh, make_production_mesh
 
@@ -42,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--telemetry", choices=["auto", "on", "off"],
                     default="auto")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

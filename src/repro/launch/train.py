@@ -22,6 +22,7 @@ from ..data.pipeline import DataConfig, ShardedLoader
 from ..models import sharding, transformer
 from ..obs import metrics as obs_metrics
 from ..runtime.checkpoint import CheckpointManager
+from ..runtime.compile_cache import setup_compile_cache
 from ..runtime.monitor import HeartbeatMonitor
 from ..training.optimizer import OptimizerConfig
 from ..training.train_loop import TrainConfig, init_train_state, train
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

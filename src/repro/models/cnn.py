@@ -195,7 +195,7 @@ def resnet34_init(key, *, n_classes=1000, cin=3, width_mult=1.0):
                    "c2": conv_init(next(keys), 3, cout, cout)}
             if st != 1 or cin_cur != cout:
                 blk["proj"] = conv_init(next(keys), 1, cin_cur, cout)
-            stage.append((blk, st))
+            stage.append(blk)
             cin_cur = cout
         params["stages"].append(stage)
     params["head"] = {"w": jax.random.normal(next(keys), (cin_cur, n_classes))
@@ -210,8 +210,9 @@ def resnet34_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT,
     x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
     if min(x.shape[1], x.shape[2]) >= 2:
         x = maxpool(x)
-    for stage in params["stages"]:
-        for blk, st in stage:
+    for stage, (_, _, first_stride) in zip(params["stages"], _R34_STAGES):
+        for b, blk in enumerate(stage):
+            st = first_stride if b == 0 else 1
             y = relu_q(cv(blk["c1"], x, stride=st), quant, qcfg)
             y = cv(blk["c2"], y)
             sc = cv(blk["proj"], x, stride=st) if "proj" in blk else x

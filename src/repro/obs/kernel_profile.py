@@ -164,7 +164,9 @@ class KernelProfiler:
 
     # ------------------------------------------------------------ readout
     def snapshot(self) -> dict:
-        """{"records": [per-(op, impl, key) rows], "programs": {...}}.
+        """{"records": [per-(op, impl, key) rows], "programs": {...},
+        "device": {"platform", "kind", "count"}} — the device names the
+        chip the times were taken on (the report looks its peaks up).
 
         Rows always carry `steady_us` when any steady sample exists:
         eagerly-timed ops report their own mean, traced ops inherit their
@@ -191,7 +193,11 @@ class KernelProfiler:
                 r["steady_source"] = (f"program:{e['program']}"
                                       if r["steady_us"] is not None else None)
             records.append(r)
-        return {"records": records, "programs": programs}
+        dev = jax.devices()[0]
+        return {"records": records, "programs": programs,
+                "device": {"platform": dev.platform,
+                           "kind": dev.device_kind,
+                           "count": len(jax.devices())}}
 
 
 PROFILER = KernelProfiler()

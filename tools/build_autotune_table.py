@@ -49,7 +49,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.analysis.roofline import HBM_BW  # noqa: E402
+from repro.analysis.roofline import device_peaks  # noqa: E402
 from repro.configs.neuromax_cnn import CONFIG  # noqa: E402
 from repro.kernels import autotune  # noqa: E402
 from repro.kernels.flash_attention import attention_traffic_bytes  # noqa: E402
@@ -68,6 +68,9 @@ ATTENTION_SHAPES = [
 ]
 
 DEFAULT_BACKENDS = ("interpret", "cpu", "tpu")
+
+# the analytic `us` estimates are bytes over a TPU v5e's HBM bandwidth
+HBM_BW = device_peaks("TPU v5 lite")["hbm_bytes_per_s"]
 
 
 def _walk_kwargs(args_or_meta) -> dict:
