@@ -459,6 +459,14 @@ def log_conv2d_fused_pallas(x, packed, scale,
     is already in the `lane_pack_codes` layout
     ``[n_sb, K*K, g_b*cin_lane, cout_g]`` (the `QuantizedTensor`
     ``"lane_packed"`` serving layout), skipping the per-call rearrange.
+
+    The steps around the kernel run under `jax.named_scope`s that name
+    their role in the compiled program's metadata: ``pad``, ``halo`` (the
+    overlapping row tiles), ``weights`` (codes and scales to the kernel's
+    layout) and ``unscramble``.  The `pallas_call` itself is left out of
+    any scope: the innermost scope would name its custom call in place of
+    ``log_conv2d_fused_pallas``, and its target, ``tpu_custom_call``,
+    already says what it is.
     """
     if prepacked:
         assert lane_pack is not None and lane_pack > 1, \
@@ -490,49 +498,55 @@ def log_conv2d_fused_pallas(x, packed, scale,
 
     # pad lead edges, then fit the trailing edge to the tiled extent (extra
     # zero rows/cols are only read into discarded stride phases)
-    xp = jnp.pad(x, ((0, 0), (ph0, 0), (pw0, 0), (0, 0)))
-    xp = _fit_dim(_fit_dim(xp, 1, Hp), 2, Wp)
-    if g_b > 1:
-        # lane-packed: pad each group's channels to its cin_lane slot and
-        # the group count to whole superblocks — channel l of superblock
-        # sb is group (sb*g_b + l//cin_lane), matching the weight lanes
-        x5 = xp.reshape(B, Hp, Wp, G, cin_g)
-        x5 = jnp.pad(x5, ((0, 0),) * 3 + ((0, n_sb * g_b - G),
-                                          (0, cin_lane - cin_g)))
-        xp = x5.reshape(B, Hp, Wp, n_sb * cin_gp)
-    elif cin_gp != cin_g:
-        x5 = xp.reshape(B, Hp, Wp, G, cin_g)
-        x5 = jnp.pad(x5, ((0, 0),) * 4 + ((0, cin_gp - cin_g),))
-        xp = x5.reshape(B, Hp, Wp, G * cin_gp)
+    with jax.named_scope("pad"):
+        xp = jnp.pad(x, ((0, 0), (ph0, 0), (pw0, 0), (0, 0)))
+        xp = _fit_dim(_fit_dim(xp, 1, Hp), 2, Wp)
+        if g_b > 1:
+            # lane-packed: pad each group's channels to its cin_lane slot
+            # and the group count to whole superblocks — channel l of
+            # superblock sb is group (sb*g_b + l//cin_lane), matching the
+            # weight lanes
+            x5 = xp.reshape(B, Hp, Wp, G, cin_g)
+            x5 = jnp.pad(x5, ((0, 0),) * 3 + ((0, n_sb * g_b - G),
+                                              (0, cin_lane - cin_g)))
+            xp = x5.reshape(B, Hp, Wp, n_sb * cin_gp)
+        elif cin_gp != cin_g:
+            x5 = xp.reshape(B, Hp, Wp, G, cin_g)
+            x5 = jnp.pad(x5, ((0, 0),) * 4 + ((0, cin_gp - cin_g),))
+            xp = x5.reshape(B, Hp, Wp, G * cin_gp)
     if n_rt == 1:
         xrt = xp                                  # rows_in == Hp
     else:
         # overlapping row tiles: duplicates only the (K-1)-row halo in HBM
-        tiles = [jax.lax.slice_in_dim(xp, i * rt * stride,
-                                      i * rt * stride + rows_in, axis=1)
-                 for i in range(n_rt)]
-        xrt = jnp.stack(tiles, axis=1).reshape(BT, rows_in, Wp, -1)
+        with jax.named_scope("halo"):
+            tiles = [jax.lax.slice_in_dim(xp, i * rt * stride,
+                                          i * rt * stride + rows_in, axis=1)
+                     for i in range(n_rt)]
+            xrt = jnp.stack(tiles, axis=1).reshape(BT, rows_in, Wp, -1)
 
-    # weights, still int8 (padding uses code 0, the dedicated zero code):
-    #   padded path:      [K, K, cin_g, Cout] → [G, taps, cin_gp, cout_gp]
-    #   lane-packed path: `lane_pack_codes` → [n_sb, taps, Lc, cout_gp]
-    if g_b > 1:
-        w = packed if prepacked else lane_pack_codes(packed, G, g_b,
-                                                     cin_lane)
-        w = jnp.pad(w, ((0, 0),) * 3 + ((0, cout_gp - cout_g),))
-    else:
-        w = packed.reshape(taps, cin_g, G, cout_g)
-        w = jnp.pad(w, ((0, 0), (0, cin_gp - cin_g), (0, 0),
-                        (0, cout_gp - cout_g)))
-        w = w.transpose(2, 0, 1, 3)
+    with jax.named_scope("weights"):
+        # codes, still int8 (padding uses code 0, the dedicated zero code):
+        #   padded path:      [K, K, cin_g, Cout] → [G, taps, cin_gp, cout_gp]
+        #   lane-packed path: `lane_pack_codes` → [n_sb, taps, Lc, cout_gp]
+        if g_b > 1:
+            w = packed if prepacked else lane_pack_codes(packed, G, g_b,
+                                                         cin_lane)
+            w = jnp.pad(w, ((0, 0),) * 3 + ((0, cout_gp - cout_g),))
+        else:
+            w = packed.reshape(taps, cin_g, G, cout_g)
+            w = jnp.pad(w, ((0, 0), (0, cin_gp - cin_g), (0, 0),
+                            (0, cout_gp - cout_g)))
+            w = w.transpose(2, 0, 1, 3)
 
-    # scales per superblock, column-matched to the kernel's (o, g) output
-    # interleave: column o*g_b + g scales group (sb*g_b + g)'s channel o
-    s = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(-1), (Cout,))
-    s = jnp.pad(s.reshape(G, cout_g), ((0, n_sb * g_b - G),
-                                       (0, cout_gp - cout_g)))
-    s = s.reshape(n_sb, g_b, cout_gp).transpose(0, 2, 1)
-    s = s.reshape(n_sb, 1, cout_gp * g_b)
+        # scales per superblock, column-matched to the kernel's (o, g)
+        # output interleave: column o*g_b + g scales group (sb*g_b + g)'s
+        # channel o
+        s = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(-1),
+                             (Cout,))
+        s = jnp.pad(s.reshape(G, cout_g), ((0, n_sb * g_b - G),
+                                           (0, cout_gp - cout_g)))
+        s = s.reshape(n_sb, g_b, cout_gp).transpose(0, 2, 1)
+        s = s.reshape(n_sb, 1, cout_gp * g_b)
 
     acc_dtype = jnp.float32
     out = pl.pallas_call(
@@ -558,10 +572,11 @@ def log_conv2d_fused_pallas(x, packed, scale,
                                  "arbitrary")),
     )(xrt, w, s)
     # unscramble: [.., n_sb, (o, g)] → group-major channels, crop padding
-    out = out.reshape(B, n_rt * rt, Wo, n_sb, cout_gp, g_b)[:, :Ho]
-    out = out.transpose(0, 1, 2, 3, 5, 4).reshape(B, Ho, Wo, n_sb * g_b,
-                                                  cout_gp)
-    return out[:, :, :, :G, :cout_g].reshape(B, Ho, Wo, Cout)
+    with jax.named_scope("unscramble"):
+        out = out.reshape(B, n_rt * rt, Wo, n_sb, cout_gp, g_b)[:, :Ho]
+        out = out.transpose(0, 1, 2, 3, 5, 4).reshape(B, Ho, Wo, n_sb * g_b,
+                                                      cout_gp)
+        return out[:, :, :, :G, :cout_g].reshape(B, Ho, Wo, Cout)
 
 
 # ---------------------------------------------------------------------------

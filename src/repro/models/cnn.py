@@ -20,6 +20,13 @@ These are real, trainable JAX models.  Two orthogonal knobs:
 
 Layer lists intentionally mirror `core/accelerator.py` so the analytical
 dataflow model and the executable model describe the same networks.
+
+Every layer of an apply runs under a `jax.named_scope` named after its
+place in the net, the same path as its weights in the param tree
+(``stem``, ``stages.1.0.c1``, ``pairs.3.dw``, ``head``): its conv, bias and
+ReLU carry the name as ``op_name`` metadata through to the compiled
+program, where a profile's device ops can be traced back to their layer.
+Scopes are metadata only; the compiled program is the same without them.
 """
 
 from __future__ import annotations
@@ -99,6 +106,12 @@ def maxpool(x, k=2, s=2):
         x, -jnp.inf, jax.lax.max, (1, k, k, 1), (1, s, s, 1), "VALID")
 
 
+def _head(p, x):
+    """Global average pool and the dense classifier, scoped ``head``."""
+    with jax.named_scope("head"):
+        return avgpool_global(x) @ p["w"] + p["b"]
+
+
 # ---------------------------------------------------------------------------
 # VGG-16
 # ---------------------------------------------------------------------------
@@ -127,12 +140,13 @@ def vgg16_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT, conv_impl=None,
                 interpret=None):
     cv = functools.partial(conv2d, quant=quant, qcfg=qcfg,
                            conv_impl=conv_impl, interpret=interpret)
-    for p, (_, pool) in zip(params["convs"], _VGG_PLAN):
-        x = relu_q(cv(p, x), quant, qcfg)
+    for i, (p, (_, pool)) in enumerate(zip(params["convs"], _VGG_PLAN)):
+        with jax.named_scope(f"convs.{i}"):
+            x = relu_q(cv(p, x), quant, qcfg)
         if pool and min(x.shape[1], x.shape[2]) >= 2:
-            x = maxpool(x)
-    x = avgpool_global(x)
-    return x @ params["head"]["w"] + params["head"]["b"]
+            with jax.named_scope(f"convs.{i}.pool"):
+                x = maxpool(x)
+    return _head(params["head"], x)
 
 
 # ---------------------------------------------------------------------------
@@ -164,13 +178,16 @@ def mobilenet_v1_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT,
                        conv_impl=None, interpret=None):
     cv = functools.partial(conv2d, quant=quant, qcfg=qcfg,
                            conv_impl=conv_impl, interpret=interpret)
-    x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
-    for pair, (_, stride) in zip(params["pairs"], _MBN_PAIRS):
+    with jax.named_scope("stem"):
+        x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
+    for i, (pair, (_, stride)) in enumerate(zip(params["pairs"], _MBN_PAIRS)):
         c = x.shape[-1]
-        x = relu_q(cv(pair["dw"], x, stride=stride, groups=c), quant, qcfg)
-        x = relu_q(cv(pair["pw"], x), quant, qcfg)
-    x = avgpool_global(x)
-    return x @ params["head"]["w"] + params["head"]["b"]
+        with jax.named_scope(f"pairs.{i}.dw"):
+            x = relu_q(cv(pair["dw"], x, stride=stride, groups=c), quant,
+                       qcfg)
+        with jax.named_scope(f"pairs.{i}.pw"):
+            x = relu_q(cv(pair["pw"], x), quant, qcfg)
+    return _head(params["head"], x)
 
 
 # ---------------------------------------------------------------------------
@@ -207,18 +224,26 @@ def resnet34_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT,
                    conv_impl=None, interpret=None):
     cv = functools.partial(conv2d, quant=quant, qcfg=qcfg,
                            conv_impl=conv_impl, interpret=interpret)
-    x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
+    with jax.named_scope("stem"):
+        x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
     if min(x.shape[1], x.shape[2]) >= 2:
-        x = maxpool(x)
-    for stage, (_, _, first_stride) in zip(params["stages"], _R34_STAGES):
+        with jax.named_scope("pool"):
+            x = maxpool(x)
+    for i, (stage, (_, _, first_stride)) in enumerate(
+            zip(params["stages"], _R34_STAGES)):
         for b, blk in enumerate(stage):
             st = first_stride if b == 0 else 1
-            y = relu_q(cv(blk["c1"], x, stride=st), quant, qcfg)
-            y = cv(blk["c2"], y)
-            sc = cv(blk["proj"], x, stride=st) if "proj" in blk else x
-            x = relu_q(y + sc, quant, qcfg)
-    x = avgpool_global(x)
-    return x @ params["head"]["w"] + params["head"]["b"]
+            with jax.named_scope(f"stages.{i}.{b}.c1"):
+                y = relu_q(cv(blk["c1"], x, stride=st), quant, qcfg)
+            with jax.named_scope(f"stages.{i}.{b}.c2"):
+                y = cv(blk["c2"], y)
+            sc = x
+            if "proj" in blk:
+                with jax.named_scope(f"stages.{i}.{b}.proj"):
+                    sc = cv(blk["proj"], x, stride=st)
+            with jax.named_scope(f"stages.{i}.{b}.add_relu"):
+                x = relu_q(y + sc, quant, qcfg)
+    return _head(params["head"], x)
 
 
 # ---------------------------------------------------------------------------
@@ -246,18 +271,27 @@ def squeezenet_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT,
                      conv_impl=None, interpret=None):
     cv = functools.partial(conv2d, quant=quant, qcfg=qcfg,
                            conv_impl=conv_impl, interpret=interpret)
-    x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
+    with jax.named_scope("stem"):
+        x = relu_q(cv(params["stem"], x, stride=2), quant, qcfg)
     if min(x.shape[1], x.shape[2]) >= 2:
-        x = maxpool(x, 3, 2)
+        with jax.named_scope("pool"):
+            x = maxpool(x, 3, 2)
     for i, fire in enumerate(params["fires"]):
         if i in (3, 7) and min(x.shape[1], x.shape[2]) >= 2:
-            x = maxpool(x, 3, 2)
-        s = relu_q(cv(fire["squeeze"], x), quant, qcfg)
-        e1 = relu_q(cv(fire["e1"], s), quant, qcfg)
-        e3 = relu_q(cv(fire["e3"], s), quant, qcfg)
-        x = jnp.concatenate([e1, e3], axis=-1)
-    x = relu_q(cv(params["final"], x), quant, qcfg)
-    return avgpool_global(x)
+            with jax.named_scope(f"fires.{i}.pool"):
+                x = maxpool(x, 3, 2)
+        with jax.named_scope(f"fires.{i}.squeeze"):
+            s = relu_q(cv(fire["squeeze"], x), quant, qcfg)
+        with jax.named_scope(f"fires.{i}.e1"):
+            e1 = relu_q(cv(fire["e1"], s), quant, qcfg)
+        with jax.named_scope(f"fires.{i}.e3"):
+            e3 = relu_q(cv(fire["e3"], s), quant, qcfg)
+        with jax.named_scope(f"fires.{i}.concat"):
+            x = jnp.concatenate([e1, e3], axis=-1)
+    with jax.named_scope("final"):
+        x = relu_q(cv(params["final"], x), quant, qcfg)
+    with jax.named_scope("head"):
+        return avgpool_global(x)
 
 
 # ---------------------------------------------------------------------------
@@ -337,8 +371,11 @@ def make_cnn(name: str, key, *, n_classes=1000, cin=3, width_mult=1.0,
              quant=None, qcfg=LOGQ_DEFAULT, conv_impl=None, interpret=None):
     init, apply = CNNS[name]
     params = init(key, n_classes=n_classes, cin=cin, width_mult=width_mult)
-    return params, functools.partial(apply, quant=quant, qcfg=qcfg,
-                                     conv_impl=conv_impl, interpret=interpret)
+    # named after the net's apply, so its jit (and the compiled module) is
+    # `jit_resnet34_apply`, not `jit__unknown`
+    forward = functools.partial(apply, quant=quant, qcfg=qcfg,
+                                conv_impl=conv_impl, interpret=interpret)
+    return params, functools.update_wrapper(forward, apply)
 
 
 def cnn_loss(apply_fn, params, batch):

@@ -17,9 +17,10 @@ Two dispatch regimes:
            per-op wall clock (XLA fuses the program), so the record
            carries shape/bytes only and is tagged with the enclosing
            **program** (`time_program`, e.g. the serving engine's
-           "prefill"/"decode" jit calls).  `snapshot()` then attributes
-           the program's measured steady time to its kernel records, so
-           per-op rows always carry a defensible steady-µs figure.
+           "prefill"/"decode" jit calls), whose own time the program
+           record holds.  Its ``steady_us`` is None: the program's time is
+           not one kernel's, and a kernel's device time comes from a
+           profiler trace of the program.
 
 Gating mirrors the tracer: ``REPRO_KERNEL_PROFILE=1`` or ``REPRO_TRACE=1``
 (a trace without kernel rows is half a trace), or `set_enabled(True)`.
@@ -96,17 +97,21 @@ class KernelProfiler:
         """Run `fn` (typically one jitted engine program) under a named
         program scope: traced kernel dispatches inside it are tagged with
         `name`, and the call is timed end-to-end via `block_until_ready`
-        (first call = compile-inclusive, later calls = steady)."""
+        (first call = compile-inclusive, later calls = steady).  The timed
+        call is a `jax.profiler.TraceAnnotation` named `name`, so under a
+        profiler session it lands on the host plane beside the device's
+        programs."""
         if not self.enabled():
             return fn()
         prev = getattr(self._local, "program", None)
         self._local.program = name
         t0 = time.perf_counter_ns()
-        try:
-            out = fn()
-        finally:
-            self._local.program = prev
-        jax.block_until_ready(out)
+        with jax.profiler.TraceAnnotation(name):
+            try:
+                out = fn()
+            finally:
+                self._local.program = prev
+            jax.block_until_ready(out)
         dt_ns = time.perf_counter_ns() - t0
         dt_us = dt_ns / 1e3
         with self._lock:
@@ -137,7 +142,6 @@ class KernelProfiler:
                 prog = self.current_program()
                 if prog is not None:
                     ent["program"] = prog
-            _trace.TRACER.instant(f"trace:{op}[{impl}]", key=key)
             return fn()
         t0 = time.perf_counter_ns()
         out = fn()
@@ -168,9 +172,9 @@ class KernelProfiler:
         "device": {"platform", "kind", "count"}} — the device names the
         chip the times were taken on (the report looks its peaks up).
 
-        Rows always carry `steady_us` when any steady sample exists:
-        eagerly-timed ops report their own mean, traced ops inherit their
-        program's steady mean (`steady_source` says which)."""
+        Eagerly-timed rows carry their own steady mean (`steady_us`,
+        `steady_source: "self"`); rows staged inside a jit have no clock
+        of their own and carry None."""
         with self._lock:
             entries = [dict(e) for e in self._entries.values()]
             programs = {n: dict(p) for n, p in self._programs.items()}
@@ -182,16 +186,11 @@ class KernelProfiler:
         for e in entries:
             r = {k: e[k] for k in ("op", "impl", "key", "bytes", "calls",
                                    "traced_calls", "first_us", "program")}
-            if e["steady_n"]:
-                r["steady_us"] = e["steady_sum"] / e["steady_n"]
-                r["steady_us_min"] = e["steady_min"]
-                r["steady_source"] = "self"
-            else:
-                prog = programs.get(e["program"]) or {}
-                r["steady_us"] = prog.get("steady_us") or prog.get("first_us")
-                r["steady_us_min"] = prog.get("steady_min")
-                r["steady_source"] = (f"program:{e['program']}"
-                                      if r["steady_us"] is not None else None)
+            steady = e["steady_n"] > 0
+            r["steady_us"] = (e["steady_sum"] / e["steady_n"] if steady
+                              else None)
+            r["steady_us_min"] = e["steady_min"]
+            r["steady_source"] = "self" if steady else None
             records.append(r)
         dev = jax.devices()[0]
         return {"records": records, "programs": programs,

@@ -21,6 +21,11 @@ enough to leave call sites unconditional.
 ``REPRO_TRACE_PATH=/path.json`` additionally auto-exports the buffer at
 interpreter exit, so any driver run under ``REPRO_TRACE=1`` leaves a
 loadable trace behind without code changes.
+
+While the tracer is on, each `span()` also enters a
+`jax.profiler.TraceAnnotation` of the same name and args, so under a JAX
+profiler session the spans (the serving engine's ``prefill``) land on the
+profiler's host plane, on its clock, beside the device planes.
 """
 
 from __future__ import annotations
@@ -32,6 +37,8 @@ import threading
 import time
 from collections import deque
 from functools import wraps
+
+from jax.profiler import TraceAnnotation
 
 _DEFAULT_CAPACITY = 65536
 _OFF = ("", "0", "false", "off")
@@ -55,11 +62,12 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation")
 
     def __init__(self, tracer, name, args):
         self._tracer, self.name, self.args = tracer, name, args
         self._t0 = 0
+        self._annotation = None
 
     def set(self, **args):
         """Attach attributes mid-span (rendered under `args` in the UI)."""
@@ -67,11 +75,14 @@ class _Span:
         return self
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         self._tracer._push(("X", self.name, self._t0, t1 - self._t0,
                             threading.get_ident(), self.args or None))
         return False

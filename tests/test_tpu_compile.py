@@ -11,7 +11,11 @@ only one process may load the TPU compiler library, and every test worker
 imports this file.
 """
 
+import contextlib
 import functools
+import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +28,14 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.log_conv2d import log_conv2d_fused_pallas
 from repro.kernels.log_matmul import log_matmul_pallas
 from repro.kernels.wkv6 import wkv6_pallas
+from repro.models.cnn import make_cnn
+from repro.serving.quantize import quantize_cnn_params
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from bench import scopes  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -116,3 +128,82 @@ def test_wkv6_compiles(one_chip):
     fn = functools.partial(wkv6_pallas, chunk=64, interpret=False)
     act = ((B, T, H, K), jnp.float32)
     _compile(fn, one_chip, act, act, act, act, ((H, K), jnp.float32))
+
+
+# net → (conv kernels, the layers that hold them)
+SCOPED_NETS = {"resnet34": (36, r"stem|stages\.\d\.\d\.(c1|c2|proj)"),
+               "mobilenet_v1": (27, r"stem|pairs\.\d+\.(dw|pw)")}
+
+
+@pytest.fixture(scope="module", params=sorted(SCOPED_NETS))
+def scoped_texts(request, one_chip):
+    """``compiled.as_text()`` of a width-0.25 forward at batch 1 (64 px) on
+    the fused Pallas conv, with its named scopes and with
+    `jax.named_scope` made a no-op; both compiled from one call site, so
+    their source locations agree."""
+    net, qcfg = request.param, CONFIG.qcfg
+
+    def build(key):
+        params, apply = make_cnn(net, key, width_mult=0.25, qcfg=qcfg,
+                                 conv_impl="pallas", interpret=False)
+        return quantize_cnn_params(params, qcfg), apply
+
+    _, apply = build(jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: build(k)[0], jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((1, 64, 64, 3), jnp.float32, sharding=one_chip)
+    texts = {}
+    for scoped in (True, False):
+        jax.clear_caches()
+        with pytest.MonkeyPatch.context() as mp:
+            if not scoped:
+                mp.setattr(jax, "named_scope",
+                           lambda name: contextlib.nullcontext())
+            texts[scoped] = jax.jit(apply).lower(params, x).compile().as_text()
+    return net, texts
+
+
+def test_every_kernel_in_a_conv_layer(scoped_texts):
+    net, texts = scoped_texts
+    n_kernels, layers = SCOPED_NETS[net]
+    text = texts[True]
+    assert text.startswith(f"HloModule jit_{net}_apply,")
+    smap = scopes.scope_map(text)
+    kernels = [(name, smap[name]) for name, hlo, _
+               in scopes.entry_instructions(text)
+               if 'custom_call_target="tpu_custom_call"' in hlo]
+    assert len(kernels) == n_kernels
+    for name, (layer, role) in kernels:
+        assert role == scopes.KERNEL, name
+        assert re.fullmatch(layers, layer), (name, layer)
+
+
+def test_ops_have_a_layer(scoped_texts):
+    smap = scopes.scope_map(scoped_texts[1][True])
+    ops = [name for name in smap
+           if not name.startswith(("copy-start", "copy-done"))]
+    named = [name for name in ops if smap[name][0] is not None]
+    assert len(named) >= 0.95 * len(ops), sorted(set(ops) - set(named))
+    roles = {role for _, role in smap.values()}
+    assert {"pad", "halo", scopes.KERNEL, scopes.GLUE} <= roles
+
+
+def test_scopes_are_metadata_only(scoped_texts):
+    """With the op_name metadata and the source tables stripped, and the
+    instructions renamed in order of first appearance (a scope can shift
+    the numbering of an instruction's name), the program is the same with
+    and without the scopes."""
+    def program(text):
+        lines = re.sub(r", metadata=\{[^}]*\}", "", text).splitlines()
+        first = next(i for i, line in enumerate(lines)
+                     if line.startswith(("%", "ENTRY")))
+        ids = {}
+        return re.sub(r"%[\w.\-]+",
+                      lambda m: ids.setdefault(m.group(0), f"%v{len(ids)}"),
+                      "\n".join(lines[:1] + lines[first:]))
+
+    texts = scoped_texts[1]
+    assert "metadata={" in texts[True]
+    assert program(texts[True]) == program(texts[False])
+    assert texts[True] != texts[False]
