@@ -216,12 +216,19 @@ def default_config(B, H, W, C, K, Cout, *, stride=1, padding="SAME",
     """Heuristic used on a tuning-table miss: MXU-sized channel blocks, the
     fewest row tiles whose grid step fits `VMEM_BUDGET_BYTES`, the batch
     tile then as wide as the budget allows, lane packing on auto (engages
-    whenever g_b ≥ 2 groups fit a lane block)."""
+    whenever g_b ≥ 2 groups fit a lane block).  A folded conv (see
+    `log_conv2d.fused_conv_geometry`) takes only whole row tiles, so its
+    patches need no padding, and no ``block_cin``, which it ignores."""
     cfg = dict(block_cin=128, block_cout=128, rows_per_tile=None,
                batch_per_tile=None, lane_pack=None)
     kw = dict(stride=stride, padding=padding, groups=groups)
-    Ho = fused_conv_geometry(B, H, W, C, K, Cout, **kw)["Ho"]
+    g0 = fused_conv_geometry(B, H, W, C, K, Cout, **kw)
+    Ho = g0["Ho"]
+    if g0["fold"]:
+        cfg["block_cin"] = None
     for n_rt in range(1, Ho + 1):
+        if g0["fold"] and Ho % n_rt:
+            continue
         rt = -(-Ho // n_rt)
         if estimate_vmem_bytes(B, H, W, C, K, Cout, **kw, **dict(
                 cfg, rows_per_tile=rt)) <= VMEM_BUDGET_BYTES:
@@ -235,12 +242,18 @@ def candidate_configs(B, H, W, C, K, Cout, *, stride=1, padding="SAME",
     """Candidate (block_cin, block_cout, rows_per_tile, batch_per_tile,
     lane_pack) tuples that fit the VMEM budget, deduped after geometry
     clamping.  For grouped shapes where lane packing can engage, each
-    tiling is tried both packed (auto g_b) and unpacked (lane_pack=1)."""
+    tiling is tried both packed (auto g_b) and unpacked (lane_pack=1).
+    A folded conv is offered whole row tiles only, and ``block_cin=None``
+    (see `default_config`)."""
     g0 = fused_conv_geometry(B, H, W, C, K, Cout, stride=stride,
                              padding=padding, groups=groups)
     Ho, cin_g, cout_g = g0["Ho"], g0["cin_g"], g0["cout_g"]
-    rts = sorted({Ho, max(1, Ho // 2), min(Ho, 8), min(Ho, 4)})
+    rts = {Ho, max(1, Ho // 2), min(Ho, 8), min(Ho, 4)}
     bcis = sorted({min(cin_g, 32), min(cin_g, 128), min(cin_g, 256)})
+    if g0["fold"]:
+        rts = {max(d for d in range(1, rt + 1) if Ho % d == 0) for rt in rts}
+        bcis = [None]
+    rts = sorted(rts)
     bcos = sorted({min(cout_g, 32), min(cout_g, 128), min(cout_g, 256)})
     bts = [1, None]  # single batch element vs widest-fit batch tile
     packable = lane_pack_geometry(groups, cin_g)["g_b"] > 1
@@ -272,7 +285,7 @@ def candidate_configs(B, H, W, C, K, Cout, *, stride=1, padding="SAME",
                         out.append(cfg)
     # prefer fewer, larger tiles first so the search front-loads likely wins
     out.sort(key=lambda c: (-(c["rows_per_tile"] or Ho),
-                            -c["block_cout"], -c["block_cin"]))
+                            -c["block_cout"], -(c["block_cin"] or 0)))
     return out[:max_candidates]
 
 

@@ -23,6 +23,13 @@ dispatch layer):
     block-diagonal `groups`× byte/FLOP waste.
     Block sizes (`block_cin/block_cout/rows_per_tile/batch_per_tile`) are
     tunable; `kernels/autotune.py` measures and persists winners.
+    A dense conv with too few input channels to fill the 128 lanes (the
+    3-channel first conv of each zoo net) has its taps **folded** into
+    channels instead: its patches (K²·Cin features per output pixel) are
+    built in HBM and run as a 1×1 conv (see `_fold_pays`), both at the
+    kernel's `DOT_PRECISION`.  Unfolded, each (8, 128) tile of its input
+    would carry 3 useful lanes of 128, and each of the K² taps would
+    contract 3 lanes.
   * ``log_conv2d_pallas`` — the explicit-im2col fallback: patches are
     materialised in HBM and tiled onto the `log_matmul_pallas` MXU kernel
     (grouped convs as a block-diagonal code matrix whose out-of-group
@@ -73,6 +80,13 @@ from .log_matmul import _decode_block, log_matmul_pallas
 from .ref import ref_log_matmul
 
 DEFAULT_CFG = LogQuantConfig()
+
+# Precision of the fused kernel's dots and of the folded conv's patch build
+# (`_fold_patches`): one setting, because on a TPU it decides how both round
+# float32 operands (DEFAULT: to bfloat16, in XLA and in Mosaic alike).  A
+# folded conv is the same arithmetic as the unfolded one only while the
+# patches carry x as the kernel's dot would have rounded it.
+DOT_PRECISION = jax.lax.Precision.DEFAULT
 
 
 # ---------------------------------------------------------------------------
@@ -128,6 +142,27 @@ def _im2col(x, K: int, stride: int, pads):
                 (1, stride, stride, 1)))
     patches = jnp.stack(taps, axis=3)            # [B, Ho, Wo, K*K, C]
     return patches.reshape(B, Ho, Wo, K * K * C), Ho, Wo
+
+
+def _fold_patches(x, K: int, stride: int, pads):
+    """x: [B, H, W, C] → its patches as one image ``[1, Ho·B, Wo, K²·C]``
+    of the conv's output pixels in (row, column, batch) order, each with
+    its features in `_im2col`'s (kh, kw, c) order.
+
+    The patches are a conv of x with a one-hot filter.  XLA's conv reads a
+    narrow-channel input once (a strided tap slice of it is a slow pass
+    over lanes) and writes its output with the batch beside the features,
+    so this layout costs no relayout.  Each feature is one product with
+    an exact 1.0 at `DOT_PRECISION`, the kernel's own: it holds x rounded
+    as the kernel's dot would round it."""
+    B, _, _, C = x.shape
+    onehot = jnp.eye(K * K * C, dtype=x.dtype).reshape(K, K, C, K * K * C)
+    p = jax.lax.conv_general_dilated(
+        x, onehot, window_strides=(stride, stride), padding=pads,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=DOT_PRECISION)
+    _, Ho, Wo, F = p.shape
+    return p.transpose(1, 2, 0, 3).reshape(1, Ho * B, Wo, F)
 
 
 def _block_diag_codes(packed, groups: int):
@@ -320,9 +355,22 @@ def _fused_vmem_bytes(*, bt, rt, rows_in, Wp, Wo, bcin, bcout, ow, taps,
     return 2 * streamed + acc + tap
 
 
+def _fold_pays(H: int, W: int, C: int, K: int, groups: int, pads, Ho: int,
+               Wo: int) -> bool:
+    """Whether a conv's taps fold into its channels: a dense conv whose
+    patches ``[Ho, Wo, K²·C]``, padded to whole 128-lane blocks, are
+    smaller than its padded input ``[Hp, Wp, C]`` padded the same way.
+    That holds where C is too narrow to fill the lanes (the 3-channel
+    first conv of every zoo net), and never for a grouped conv."""
+    (ph0, ph1), (pw0, pw1) = pads
+    return (groups == 1 and K > 1
+            and Ho * Wo * _ceil_to(K * K * C, LANES)
+            < (H + ph0 + ph1) * (W + pw0 + pw1) * _ceil_to(C, LANES))
+
+
 def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
                         *, stride: int = 1, padding="SAME", groups: int = 1,
-                        block_cin: int = 128, block_cout: int = 128,
+                        block_cin: int | None = 128, block_cout: int = 128,
                         rows_per_tile: int | None = None,
                         batch_per_tile: int | None = None,
                         lane_pack: int | None = None) -> dict:
@@ -340,7 +388,40 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
     grid dimension shrinks to ``n_sb = ceil(groups/g_b)``, and each
     output block is ``ow = bcout*g_b`` channels wide (``bcout`` output
     channels for each of the block's groups, interleaved o-major).
+
+    When the taps fold (``fold``, see `_fold_pays`), the result is the
+    folded launch's: a 1×1, stride-1, VALID conv over the patches laid out
+    as one image ``[1, Ho·B, Wo, K²·C]`` (`_fold_patches`), that contracts
+    all ``K²·C`` lanes in one reduction block (``block_cin`` is ignored,
+    and the autotuner stores it as None; rows are the image's,
+    ``batch_per_tile`` counts row tiles), with ``fold_pads`` the conv's
+    own padding, which the patches take in.  A ``rows_per_tile`` that does
+    not divide ``Ho·B`` pads the patches to whole tiles, a copy: the
+    autotuner offers only divisors there.
     """
+    pads = normalize_padding(padding, K, stride, H, W)
+    Ho = _out_size(H, K, stride, pads[0])
+    Wo = _out_size(W, K, stride, pads[1])
+    if _fold_pays(H, W, C, K, groups, pads, Ho, Wo):
+        g = _direct_geometry(
+            1, Ho * B, Wo, K * K * C, 1, Cout, stride=1, padding="VALID",
+            groups=1, block_cin=K * K * C, block_cout=block_cout,
+            rows_per_tile=rows_per_tile, batch_per_tile=batch_per_tile,
+            lane_pack=None)
+        return dict(g, fold=True, fold_pads=pads)
+    return _direct_geometry(B, H, W, C, K, Cout, stride=stride,
+                            padding=padding, groups=groups,
+                            block_cin=block_cin, block_cout=block_cout,
+                            rows_per_tile=rows_per_tile,
+                            batch_per_tile=batch_per_tile,
+                            lane_pack=lane_pack)
+
+
+def _direct_geometry(B, H, W, C, K, Cout, *, stride, padding, groups,
+                     block_cin, block_cout, rows_per_tile, batch_per_tile,
+                     lane_pack) -> dict:
+    """`fused_conv_geometry` of the launch with the taps as they are: the
+    conv's own, or a folded conv's 1×1 launch over its patches."""
     pads = normalize_padding(padding, K, stride, H, W)
     Ho = _out_size(H, K, stride, pads[0])
     Wo = _out_size(W, K, stride, pads[1])
@@ -378,7 +459,7 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
                 cout_gp=cout_gp, rows_in=rows_in, Wp=Wp, Hp=Hp, BT=BT, bt=bt,
                 ncb=cin_gp // bcin, njb=cout_gp // bcout, taps=K * K,
                 g_b=g_b, cin_lane=cin_lane, n_sb=n_sb, ow=bcout * g_b,
-                vmem=vmem(bt=bt))
+                vmem=vmem(bt=bt), fold=False)
 
 
 def _fused_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
@@ -416,7 +497,8 @@ def _fused_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
             col_g = jax.lax.broadcasted_iota(jnp.int32, (Lc, g_b), 1)
             mask = (lane_g == col_g).astype(acc_dtype)   # [Lc, g_b]
             w = (w[:, :, None] * mask[:, None, :]).reshape(Lc, bcout * g_b)
-        acc_ref[...] += jnp.dot(patch, w, preferred_element_type=acc_dtype)
+        acc_ref[...] += jnp.dot(patch, w, precision=DOT_PRECISION,
+                                preferred_element_type=acc_dtype)
 
     @pl.when(c == pl.num_programs(3) - 1)
     def _flush():
@@ -432,7 +514,7 @@ def log_conv2d_fused_pallas(x, packed, scale,
                             cfg: LogQuantConfig = DEFAULT_CFG, *,
                             stride: int = 1, padding="SAME", groups: int = 1,
                             interpret: bool = False, out_dtype=None,
-                            block_cin: int = 128, block_cout: int = 128,
+                            block_cin: int | None = 128, block_cout: int = 128,
                             rows_per_tile: int | None = None,
                             batch_per_tile: int | None = None,
                             lane_pack: int | None = None,
@@ -460,13 +542,23 @@ def log_conv2d_fused_pallas(x, packed, scale,
     ``[n_sb, K*K, g_b*cin_lane, cout_g]`` (the `QuantizedTensor`
     ``"lane_packed"`` serving layout), skipping the per-call rearrange.
 
+    A dense conv folds its taps into channels where its input channels
+    are too few to fill the lanes (`_fold_pays`, decided from the shape
+    alone by `fused_conv_geometry`): with 3 channels each (8, 128) tile of
+    the input carries 3 useful lanes, and each tap's dot contracts 3 lanes
+    of 128.  Folded, the patches (`_fold_patches`) are built in HBM and
+    the same kernel runs them as a 1×1, stride-1 VALID conv that
+    contracts all ``K²·Cin`` lanes in one reduction block, whatever
+    ``block_cin`` asks.  Row tiles that do not overlap (K = 1, so every
+    folded launch) are a reshape of the input, not a copy.
+
     The steps around the kernel run under `jax.named_scope`s that name
-    their role in the compiled program's metadata: ``pad``, ``halo`` (the
-    overlapping row tiles), ``weights`` (codes and scales to the kernel's
-    layout) and ``unscramble``.  The `pallas_call` itself is left out of
-    any scope: the innermost scope would name its custom call in place of
-    ``log_conv2d_fused_pallas``, and its target, ``tpu_custom_call``,
-    already says what it is.
+    their role in the compiled program's metadata: ``fold`` (the
+    patches), ``pad``, ``halo`` (the overlapping row tiles), ``weights``
+    (codes and scales to the kernel's layout) and ``unscramble``.  The
+    `pallas_call` itself is left out of any scope: the innermost scope
+    would name its custom call in place of ``log_conv2d_fused_pallas``,
+    and its target, ``tpu_custom_call``, already says what it is.
     """
     if prepacked:
         assert lane_pack is not None and lane_pack > 1, \
@@ -483,6 +575,14 @@ def log_conv2d_fused_pallas(x, packed, scale,
         block_cin=block_cin, block_cout=block_cout,
         rows_per_tile=rows_per_tile, batch_per_tile=batch_per_tile,
         lane_pack=lane_pack)
+    B0 = B
+    if g["fold"]:
+        # taps into channels: patches in (kh, kw, c) order, the order of
+        # the HWIO codes' rows, so the codes serve unchanged
+        with jax.named_scope("fold"):
+            x = _fold_patches(x, K, stride, g["fold_pads"])
+        packed = packed.reshape(1, 1, K * K * C, Cout)
+        B, K, stride = 1, 1, 1
     G, taps = groups, g["taps"]
     (ph0, _), (pw0, _) = g["pads"]
     Ho, Wo, rt, n_rt, bt = g["Ho"], g["Wo"], g["rt"], g["n_rt"], g["bt"]
@@ -514,8 +614,10 @@ def log_conv2d_fused_pallas(x, packed, scale,
             x5 = xp.reshape(B, Hp, Wp, G, cin_g)
             x5 = jnp.pad(x5, ((0, 0),) * 4 + ((0, cin_gp - cin_g),))
             xp = x5.reshape(B, Hp, Wp, G * cin_gp)
-    if n_rt == 1:
-        xrt = xp                                  # rows_in == Hp
+    if n_rt * rows_in == Hp:
+        # tiles that do not overlap (one tile, or K = 1 as in every folded
+        # launch) are the padded rows as they lie: a reshape, not a copy
+        xrt = xp.reshape(BT, rows_in, Wp, -1)
     else:
         # overlapping row tiles: duplicates only the (K-1)-row halo in HBM
         with jax.named_scope("halo"):
@@ -576,7 +678,10 @@ def log_conv2d_fused_pallas(x, packed, scale,
         out = out.reshape(B, n_rt * rt, Wo, n_sb, cout_gp, g_b)[:, :Ho]
         out = out.transpose(0, 1, 2, 3, 5, 4).reshape(B, Ho, Wo, n_sb * g_b,
                                                       cout_gp)
-        return out[:, :, :, :G, :cout_g].reshape(B, Ho, Wo, Cout)
+        out = out[:, :, :, :G, :cout_g].reshape(B, Ho, Wo, Cout)
+        if g["fold"]:   # the patches' pixel order back to NHWC
+            out = out.reshape(Ho // B0, Wo, B0, Cout).transpose(2, 0, 1, 3)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -592,9 +697,15 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
     """Bytes moved HBM↔VMEM for one conv call, per implementation.
 
     First-order model: counts every block fetch/spill the grid actually
-    performs (patch materialisation write+read, per-output-block activation
-    re-reads, per-tile weight re-reads) and ignores sub-block padding waste.
-    Returns ``{"act": ..., "w": ..., "out": ..., "act_w": ..., "total": ...}``.
+    performs (patch materialisation write+read, the fused path's halo row
+    tiles and folded patches written and read, per-output-block activation
+    re-reads, per-tile weight re-reads) and ignores sub-block padding
+    waste.
+    Returns ``{"act": ..., "w": ..., "out": ..., "act_w": ..., "total": ...}``;
+    fused rows add ``act_kernel``, the part of ``act`` the kernel's own
+    grid fetches.  ``"pallas_direct"`` is the fused launch with the taps
+    left unfolded whatever `_fold_pays` decides: what a fold is judged
+    against.
 
     ``lanes`` models the physical lane width of the fused path's channel
     blocks: a real TPU DMAs (and contracts) whole 128-lane blocks, so a
@@ -628,18 +739,32 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
         n_i = -(-(B * Ho * Wo) // matmul_block)
         act = patch_b * (2 + n_j)
         w = K * K * groups * cin_g * Cout * code_itemsize * n_i
-    elif impl in ("pallas", "pallas_fused"):
-        g = fused_conv_geometry(B, H, W, C, K, Cout, stride=stride,
-                                padding=padding, groups=groups,
-                                **(config or {}))
+    elif impl in ("pallas", "pallas_fused", "pallas_direct"):
+        cfg = {**dict(block_cin=128, block_cout=128, rows_per_tile=None,
+                      batch_per_tile=None, lane_pack=None), **(config or {})}
+        geometry = (_direct_geometry if impl == "pallas_direct"
+                    else fused_conv_geometry)
+        g = geometry(B, H, W, C, K, Cout, stride=stride, padding=padding,
+                     groups=groups, **cfg)
         n_bt = g["BT"] // g["bt"]
         # fetched channel width per (superblock, reduction step), padded to
         # whole physical lane blocks; g_b=1 ⇒ n_sb=groups, bcin·ncb=cin_gp
         ch = g["n_sb"] * g["ncb"] * _ceil_to(g["bcin"], lanes)
-        act = (n_bt * g["bt"] * g["rows_in"] * g["Wp"] * ch
-               * act_itemsize * g["njb"])
+        act = act_kernel = (n_bt * g["bt"] * g["rows_in"] * g["Wp"] * ch
+                            * act_itemsize * g["njb"])
         w = (g["n_sb"] * g["taps"] * g["ncb"] * _ceil_to(g["bcin"], lanes)
              * g["cout_gp"] * code_itemsize * n_bt)
+        if g["n_rt"] * g["rows_in"] > g["Hp"]:
+            # overlapping row tiles are stacked in HBM first: the padded
+            # input read once, every tile with its halo written once
+            act += ((B * g["Hp"] + g["BT"] * g["rows_in"]) * g["Wp"] * ch
+                    * act_itemsize)
+        if g["fold"]:
+            # the patches the launch reads are built in HBM first: x read
+            # once, the patches written once
+            act += ((B * H * W * C + B * Ho * Wo * _ceil_to(K * K * C, lanes))
+                    * act_itemsize)
+            cin_g = K * K * C
         density = (groups * cin_g) / (g["n_sb"] * g["ncb"]
                                       * _ceil_to(g["bcin"], LANES))
     else:
@@ -648,6 +773,7 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
            "act_w": int(act + w), "total": int(act + w + out_b)}
     if density is not None:
         out["lane_density"] = round(min(density, 1.0), 4)
+        out["act_kernel"] = int(act_kernel)
     return out
 
 

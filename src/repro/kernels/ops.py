@@ -36,12 +36,14 @@ import jax.numpy as jnp
 from repro.core.logquant import (LogQuantConfig, QuantizedTensor,
                                  quantize_tensor)
 from repro.obs import kernel_profile as _kprof
+from repro.obs import metrics as _obs_metrics
 from . import autotune as _autotune
 from . import ref as _ref
 from .flash_attention import attention_traffic_bytes, flash_attention_pallas
-from .log_conv2d import (conv_traffic_bytes, lane_unpack_codes,
-                         log_conv2d_blockwise, log_conv2d_fused_pallas,
-                         log_conv2d_pallas, log_conv2d_ref)
+from .log_conv2d import (conv_traffic_bytes, fused_conv_geometry,
+                         lane_unpack_codes, log_conv2d_blockwise,
+                         log_conv2d_fused_pallas, log_conv2d_pallas,
+                         log_conv2d_ref)
 from .log_matmul import log_matmul_pallas
 from .wkv6 import wkv6_chunked_jnp, wkv6_pallas
 
@@ -292,6 +294,9 @@ def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
             config = explicit
         if prepacked:  # the baked layout forces its own lane_pack factor
             config = dict(config, lane_pack=lane_meta[0])
+        fold = fused_conv_geometry(B, H, W, C, K, Cout, **shape_kw)["fold"]
+        _obs_metrics.REGISTRY.counter(
+            "conv_fold", result="folded" if fold else "direct").inc()
         call = lambda: log_conv2d_fused_pallas(x, packed, qt.scale, qt.cfg,
                                                interpret=interp,
                                                prepacked=prepacked, **kw,

@@ -6,7 +6,10 @@ import numpy as np
 import pytest
 
 from repro.configs.neuromax_cnn import CONFIG
-from repro.models.cnn import CNNS, cnn_loss, make_cnn
+from repro.kernels.log_conv2d import fused_conv_geometry
+from repro.models.cnn import CNNS, cnn_loss, make_cnn, trace_conv_shapes
+from repro.obs import metrics as obs_metrics
+from repro.serving.quantize import quantize_cnn_params
 
 RED = CONFIG.reduced()
 
@@ -104,6 +107,56 @@ def test_cnn_conv_impl_fused_pallas_matches_blockwise():
     lb = np.asarray(apply_bw(params, x))
     lz = np.asarray(apply_fz(params, x))
     np.testing.assert_allclose(lz, lb, atol=1e-3 * (np.abs(lb).max() + 1))
+
+
+@pytest.mark.parametrize("batch", [1, 32])
+@pytest.mark.parametrize("name", sorted(CNNS))
+def test_only_the_first_conv_folds(name, batch):
+    """At full width and 224 px the fold rule holds for each net's
+    3-channel first conv and for no other."""
+    folds = [fused_conv_geometry(s["B"], s["H"], s["W"], s["C"], s["K"],
+                                 s["Cout"], stride=s["stride"],
+                                 padding=s["padding"],
+                                 groups=s["groups"])["fold"]
+             for s in trace_conv_shapes(name, batch=batch)]
+    assert folds == [True] + [False] * (len(folds) - 1)
+
+
+@pytest.mark.parametrize("name,n_convs", [("resnet34", 36),
+                                          ("mobilenet_v1", 27)])
+def test_trace_conv_shapes_unchanged_by_the_fold(name, n_convs):
+    """The walker records the convs as the nets call them, folded or not:
+    the benchmark's counts read the same work."""
+    records = trace_conv_shapes(name)
+    assert len(records) == n_convs
+    assert all(set(r) == {"B", "H", "W", "C", "K", "Cout", "stride",
+                          "padding", "groups"} for r in records)
+    assert records[0] == dict(B=1, H=224, W=224, C=3,
+                              K=5 if name == "resnet34" else 3,
+                              Cout=64 if name == "resnet34" else 32,
+                              stride=2, padding="SAME", groups=1)
+
+
+@pytest.mark.parametrize("name,n_convs", [("resnet34", 36),
+                                          ("mobilenet_v1", 27)])
+def test_traced_forward_counts_one_fold(name, n_convs):
+    """Each fused dispatch counts `conv_fold` once: one forward of the
+    full-width net at 224 px folds its first conv and no other."""
+    init, apply = CNNS[name]
+
+    def counts():
+        return [obs_metrics.REGISTRY.counter("conv_fold", result=r).value
+                for r in ("folded", "direct")]
+
+    def forward(key, x):
+        return apply(quantize_cnn_params(init(key), CONFIG.qcfg), x,
+                     conv_impl="pallas")
+
+    before = counts()
+    jax.eval_shape(forward, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                   jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32))
+    after = counts()
+    assert [a - b for a, b in zip(after, before)] == [1, n_convs - 1]
 
 
 def test_cnn_train_step_reduces_loss():

@@ -83,6 +83,115 @@ def test_fused_tiling_configs_agree(config):
 
 
 # ---------------------------------------------------------------------------
+# taps folded into channels; row tiles that do not overlap
+# ---------------------------------------------------------------------------
+
+FOLD_SHAPES = [  # B, H, W, C, K, P, stride, padding, config
+    (1, 12, 12, 3, 3, 8, 1, "SAME", dict(rows_per_tile=4)),
+    (2, 13, 11, 3, 3, 8, 2, "SAME", dict(rows_per_tile=2)),
+    (1, 12, 12, 1, 3, 6, 1, "VALID", dict(rows_per_tile=3)),
+    (2, 16, 16, 3, 5, 8, 2, ((1, 2), (1, 2)), dict(rows_per_tile=3)),
+    (1, 14, 14, 3, 5, 8, 1, "SAME", dict(rows_per_tile=5, block_cin=3)),
+    (1, 9, 9, 1, 5, 4, 2, 2, dict(rows_per_tile=2, batch_per_tile=1)),
+    (1, 16, 16, 3, 7, 16, 2, "SAME", dict(rows_per_tile=3, block_cin=3)),
+    (2, 12, 10, 1, 7, 4, 1, ((3, 2), (2, 3)), dict(rows_per_tile=4)),
+    (1, 15, 15, 3, 7, 8, 2, "VALID", dict(rows_per_tile=2)),
+    # K = 1: never folded; its row tiles are a reshape of the input
+    (2, 10, 10, 4, 1, 8, 1, "VALID", dict(rows_per_tile=3)),
+    (1, 10, 10, 4, 1, 8, 2, "VALID", dict(rows_per_tile=2)),
+]
+
+
+@pytest.mark.parametrize("B,H,W,C,K,P,stride,padding,config", FOLD_SHAPES)
+def test_fused_fold_and_reshape_agree_with_ref(B, H, W, C, K, P, stride,
+                                               padding, config):
+    """A dense conv with too few channels to fill the lanes runs folded: its
+    patches as one 1×1 launch that contracts all K²·C lanes in one block,
+    whatever ``block_cin`` asks.  Every launch here has several row tiles
+    that do not overlap, so its input reaches the kernel as a reshape.
+    Both are the same conv as the oracle."""
+    from repro.kernels.log_conv2d import fused_conv_geometry
+    kw = dict(stride=stride, padding=padding)
+    g = fused_conv_geometry(B, H, W, C, K, P, **kw, **config)
+    assert g["fold"] == (K > 1)
+    if g["fold"]:
+        assert (g["taps"], g["ncb"], g["bcin"]) == (1, 1, K * K * C)
+    assert g["n_rt"] > 1 and g["n_rt"] * g["rows_in"] == g["Hp"]
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(size=(B, H, W, C)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(K, K, C, P)).astype(np.float32))
+    qt = quantize_tensor(w)
+    y_ref = ops.conv2d(x, qt, impl="ref", **kw)
+    y = ops.conv2d(x, qt, impl="pallas", interpret=True, config=dict(config),
+                   **kw)
+    assert y.shape == y_ref.shape
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=1e-4 * float(jnp.max(jnp.abs(y_ref)) + 1))
+
+
+def test_fold_rounds_patches_as_the_kernel_rounds_x():
+    """The folded launch builds its patches and runs its kernel's dots at
+    one precision, `DOT_PRECISION`: on a TPU that setting decides how both
+    round float32 operands, so the folded conv stays the unfolded one's
+    arithmetic only while the two agree."""
+    from repro.kernels.log_conv2d import DOT_PRECISION
+    x = jax.ShapeDtypeStruct((1, 12, 12, 3), jnp.float32)
+    qt = quantize_tensor(jnp.ones((3, 3, 3, 8), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda x: ops.conv2d(
+        x, qt, impl="pallas", interpret=True))(x)
+    found = {}
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name in ("conv_general_dilated", "dot_general"):
+                found.setdefault(eqn.primitive.name, set()).add(
+                    eqn.params["precision"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    want = {(DOT_PRECISION, DOT_PRECISION)}
+    assert found == {"conv_general_dilated": want, "dot_general": want}
+
+
+def test_folded_configs_are_the_launch_that_runs():
+    """The autotuner offers a folded conv only row tiles that divide its
+    ``Ho·B`` patch rows, and no ``block_cin`` (the launch contracts all
+    K²·Cin lanes in one block): the config it persists is the launch."""
+    from repro.kernels import autotune
+    from repro.kernels.log_conv2d import fused_conv_geometry
+    for args in [(1, 224, 224, 3, 3, 32), (32, 224, 224, 3, 5, 64),
+                 (1, 226, 226, 3, 3, 8)]:       # 113 rows: a prime
+        kw = dict(stride=2, padding="SAME")
+        rows = fused_conv_geometry(*args, **kw)["Ho"]
+        cfgs = (autotune.candidate_configs(*args, **kw)
+                + [autotune.default_config(*args, **kw)])
+        assert cfgs
+        for cfg in cfgs:
+            g = fused_conv_geometry(*args, **kw, **cfg)
+            assert g["fold"] and cfg["block_cin"] is None
+            assert rows % cfg["rows_per_tile"] == 0
+            assert (g["rt"], g["Hp"]) == (cfg["rows_per_tile"], rows)
+
+
+def test_fold_cuts_stem_traffic(monkeypatch):
+    """At the 128-lane width the ResNet-34 stem at batch 32 moves at least
+    4× fewer bytes folded than unfolded: its 3-channel input no longer
+    fills 128 lanes, nor do the halo row tiles stacked from it."""
+    from repro.kernels import autotune, log_conv2d
+    args, kw = (32, 224, 224, 3, 5, 64), dict(stride=2, padding="SAME")
+
+    def total():
+        return log_conv2d.conv_traffic_bytes(
+            "pallas", *args, **kw, lanes=128,
+            config=autotune.default_config(*args, **kw))["total"]
+
+    folded = total()
+    monkeypatch.setattr(log_conv2d, "_fold_pays", lambda *a: False)
+    assert 4 * folded <= total()
+
+
+# ---------------------------------------------------------------------------
 # lane-packed grouped/depthwise layout
 # ---------------------------------------------------------------------------
 

@@ -67,8 +67,11 @@ def _compile(fn, one_chip, *shapes):
 
 # (x shape, K, Cout, stride, groups): the two shapes Mosaic refused for an
 # unaligned tap slice, the batch-8 table miss that overran VMEM, the
-# ResNet-34 stem, and lane-packed depthwise layers (4 superblocks; batch 8
-# stride 2)
+# ResNet-34 stem, lane-packed depthwise layers (4 superblocks; batch 8
+# stride 2), folded stems: the ResNet-34 and MobileNet v1 stems at batch
+# 32 and the published 7×7 ResNet stem (147 contraction lanes), and the
+# packaged table's SqueezeNet and VGG-16 3×3 winners that counting the halo
+# stack in `conv_traffic_bytes` re-picked
 CONV_SHAPES = [
     ((1, 7, 7, 512), 3, 512, 1, 1),
     ((1, 14, 14, 256), 3, 512, 2, 1),
@@ -76,6 +79,12 @@ CONV_SHAPES = [
     ((1, 224, 224, 3), 5, 64, 2, 1),
     ((1, 14, 14, 512), 3, 512, 1, 512),
     ((8, 112, 112, 64), 3, 64, 2, 64),
+    ((32, 224, 224, 3), 5, 64, 2, 1),
+    ((32, 224, 224, 3), 3, 32, 2, 1),
+    ((1, 224, 224, 3), 7, 64, 2, 1),
+    ((1, 55, 55, 32), 3, 128, 1, 1),
+    ((1, 56, 56, 128), 3, 256, 1, 1),
+    ((1, 56, 56, 256), 3, 256, 1, 1),
 ]
 
 
@@ -186,7 +195,7 @@ def test_ops_have_a_layer(scoped_texts):
     named = [name for name in ops if smap[name][0] is not None]
     assert len(named) >= 0.95 * len(ops), sorted(set(ops) - set(named))
     roles = {role for _, role in smap.values()}
-    assert {"pad", "halo", scopes.KERNEL, scopes.GLUE} <= roles
+    assert {"pad", "fold", scopes.KERNEL, scopes.GLUE} <= roles
 
 
 def test_scopes_are_metadata_only(scoped_texts):
