@@ -23,9 +23,10 @@ dataflow model and the executable model describe the same networks.
 
 Every layer of an apply runs under a `jax.named_scope` named after its
 place in the net, the same path as its weights in the param tree
-(``stem``, ``stages.1.0.c1``, ``pairs.3.dw``, ``head``): its conv, bias and
-ReLU carry the name as ``op_name`` metadata through to the compiled
-program, where a profile's device ops can be traced back to their layer.
+(``stem``, ``stages.1.0.c1``, ``pairs.3.dw``, ``fcs.0``, ``head``): its
+conv, bias and ReLU carry the name as ``op_name`` metadata through to the
+compiled program, where a profile's device ops can be traced back to
+their layer.
 Scopes are metadata only; the compiled program is the same without them.
 """
 
@@ -122,22 +123,49 @@ _VGG_PLAN = [  # (Cout, pool_after)
     (512, False), (512, False), (512, True),
     (512, False), (512, False), (512, True),
 ]
+_VGG_MAP = 7  # side of the map the classifier reads: 224 px / 2**5
 
 
 def vgg16_init(key, *, n_classes=1000, cin=3, width_mult=1.0):
-    keys = jax.random.split(key, len(_VGG_PLAN) + 1)
+    """The 13 convs of Table 1 column D and the published classifier,
+    FC-4096, FC-4096 and FC-n_classes, held as the convs `vgg16_apply`
+    runs them: a 7×7 conv over the 7×7 map, then two 1×1 convs."""
+    keys = jax.random.split(key, len(_VGG_PLAN) + 3)
     params, c = [], cin
     for i, (cout, _) in enumerate(_VGG_PLAN):
         cout = max(8, int(cout * width_mult))
         params.append(conv_init(keys[i], 3, c, cout))
         c = cout
-    head = {"w": jax.random.normal(keys[-1], (c, n_classes)) * (1 / c) ** 0.5,
-            "b": jnp.zeros((n_classes,))}
-    return {"convs": params, "head": head}
+    f = max(8, int(4096 * width_mult))
+    k6, k7, k8 = keys[len(_VGG_PLAN):]
+    fcs = [conv_init(k6, _VGG_MAP, c, f), conv_init(k7, 1, f, f),
+           conv_init(k8, 1, f, n_classes)]
+    return {"convs": params, "fcs": fcs}
+
+
+def _bins(n: int, out: int) -> list[tuple[int, int]]:
+    """The ``[start, end)`` of each of ``out`` adaptive-pool bins over
+    ``n`` pixels, as torchvision's `AdaptiveAvgPool2d` draws them."""
+    return [(i * n // out, -(-(i + 1) * n // out)) for i in range(out)]
+
+
+def adaptive_avgpool(x, out: int):
+    """[B, H, W, C] → [B, out, out, C], each pixel the mean of its bin
+    (bins overlap where ``out`` does not divide the side, and repeat a
+    pixel where the side is shorter than ``out``)."""
+    x = jnp.stack([jnp.mean(x[:, a:b], axis=1)
+                   for a, b in _bins(x.shape[1], out)], axis=1)
+    return jnp.stack([jnp.mean(x[:, :, a:b], axis=2)
+                      for a, b in _bins(x.shape[2], out)], axis=2)
 
 
 def vgg16_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT, conv_impl=None,
                 interpret=None):
+    """The convs and pools, then the classifier as dense evaluation (§3.2):
+    FC6 a VALID 7×7 conv over the 7×7 map, FC7 and FC8 1×1 convs, ReLU
+    after FC6 and FC7 (dropout is training-only).  At 224 px the map is
+    7×7, and this is flatten and dense exactly; a smaller image's map is
+    first average-pooled to 7×7 (``fcs.pool``)."""
     cv = functools.partial(conv2d, quant=quant, qcfg=qcfg,
                            conv_impl=conv_impl, interpret=interpret)
     for i, (p, (_, pool)) in enumerate(zip(params["convs"], _VGG_PLAN)):
@@ -146,7 +174,16 @@ def vgg16_apply(params, x, *, quant=None, qcfg=LOGQ_DEFAULT, conv_impl=None,
         if pool and min(x.shape[1], x.shape[2]) >= 2:
             with jax.named_scope(f"convs.{i}.pool"):
                 x = maxpool(x)
-    return _head(params["head"], x)
+    if x.shape[1:3] != (_VGG_MAP, _VGG_MAP):
+        with jax.named_scope("fcs.pool"):
+            x = adaptive_avgpool(x, _VGG_MAP)
+    fc6, fc7, fc8 = params["fcs"]
+    with jax.named_scope("fcs.0"):
+        x = relu_q(cv(fc6, x, pad="VALID"), quant, qcfg)
+    with jax.named_scope("fcs.1"):
+        x = relu_q(cv(fc7, x, pad="VALID"), quant, qcfg)
+    with jax.named_scope("fcs.2"):
+        return cv(fc8, x, pad="VALID").reshape(x.shape[0], -1)
 
 
 # ---------------------------------------------------------------------------

@@ -138,7 +138,8 @@ def test_trace_conv_shapes_unchanged_by_the_fold(name, n_convs):
 
 
 @pytest.mark.parametrize("name,n_convs", [("resnet34", 36),
-                                          ("mobilenet_v1", 27)])
+                                          ("mobilenet_v1", 27),
+                                          ("vgg16", 16)])
 def test_traced_forward_counts_one_fold(name, n_convs):
     """Each fused dispatch counts `conv_fold` once: one forward of the
     full-width net at 224 px folds its first conv and no other."""
@@ -157,6 +158,44 @@ def test_traced_forward_counts_one_fold(name, n_convs):
                    jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32))
     after = counts()
     assert [a - b for a, b in zip(after, before)] == [1, n_convs - 1]
+
+
+def test_vgg16_has_the_published_parameter_count():
+    """Simonyan & Zisserman 2015, Table 2: 138M for config D; 89% of it is
+    FC6, the 7×7 conv from 512 to 4096 channels."""
+    init, _ = CNNS["vgg16"]
+    params = jax.eval_shape(init, jax.random.PRNGKey(0))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n == 138_357_544
+    assert params["fcs"][0]["w"].shape == (7, 7, 512, 4096)
+    assert [p["w"].shape[:2] for p in params["fcs"][1:]] == [(1, 1)] * 2
+
+
+def test_vgg16_classifier_runs_on_packed_codes(monkeypatch):
+    """With ``conv_impl="auto"`` and packed weights the three layers of
+    the classifier dispatch through `kernels/ops.conv2d` like every conv:
+    16 launches, the last three a VALID 7×7 and two 1×1s."""
+    from repro.core.logquant import QuantizedTensor
+    from repro.kernels import ops as kops
+    calls = []
+    dispatch = kops.conv2d
+
+    def spy(x, qt, **kw):
+        calls.append((isinstance(qt, QuantizedTensor), tuple(qt.shape),
+                      kw["padding"], kw["impl"]))
+        return dispatch(x, qt, **kw)
+
+    monkeypatch.setattr(kops, "conv2d", spy)
+    init, apply = CNNS["vgg16"]
+    jax.eval_shape(lambda k, x: apply(quantize_cnn_params(init(k)), x,
+                                      conv_impl="auto"),
+                   jax.ShapeDtypeStruct((2,), jnp.uint32),
+                   jax.ShapeDtypeStruct((2, 224, 224, 3), jnp.float32))
+    assert len(calls) == 16
+    assert all(packed and impl == "auto" for packed, _, _, impl in calls)
+    assert [c[1:3] for c in calls[13:]] == [
+        ((7, 7, 512, 4096), "VALID"), ((1, 1, 4096, 4096), "VALID"),
+        ((1, 1, 4096, 1000), "VALID")]
 
 
 def test_cnn_train_step_reduces_loss():

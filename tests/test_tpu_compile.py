@@ -65,35 +65,51 @@ def _compile(fn, one_chip, *shapes):
     assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
 
 
-# (x shape, K, Cout, stride, groups): the two shapes Mosaic refused for an
-# unaligned tap slice, the batch-8 table miss that overran VMEM, the
-# ResNet-34 stem, lane-packed depthwise layers (4 superblocks; batch 8
-# stride 2), folded stems: the ResNet-34 and MobileNet v1 stems at batch
-# 32 and the published 7×7 ResNet stem (147 contraction lanes), and the
+# (x shape, K, Cout, stride, groups, padding): the two shapes Mosaic
+# refused for an unaligned tap slice, the batch-8 table miss that overran
+# VMEM, the ResNet-34 stem, lane-packed depthwise layers (4 superblocks;
+# batch 8 stride 2), folded stems: the ResNet-34 and MobileNet v1 stems at
+# batch 32 and the published 7×7 ResNet stem (147 contraction lanes), the
 # packaged table's SqueezeNet and VGG-16 3×3 winners that counting the halo
-# stack in `conv_traffic_bytes` re-picked
+# stack in `conv_traffic_bytes` re-picked, and VGG-16 at batch 32: its
+# classifier as dense-evaluation convs (FC6 a VALID 7×7 conv whose 102.8 MB
+# of codes dwarf its 3.2 MB input, FC7 and FC8 1×1 convs over one pixel;
+# FC6 at batch 1 too, with the packaged table's config) and `convs.1`, the
+# 3×3 conv on the zoo's largest maps
 CONV_SHAPES = [
-    ((1, 7, 7, 512), 3, 512, 1, 1),
-    ((1, 14, 14, 256), 3, 512, 2, 1),
-    ((8, 56, 56, 64), 3, 64, 1, 1),
-    ((1, 224, 224, 3), 5, 64, 2, 1),
-    ((1, 14, 14, 512), 3, 512, 1, 512),
-    ((8, 112, 112, 64), 3, 64, 2, 64),
-    ((32, 224, 224, 3), 5, 64, 2, 1),
-    ((32, 224, 224, 3), 3, 32, 2, 1),
-    ((1, 224, 224, 3), 7, 64, 2, 1),
-    ((1, 55, 55, 32), 3, 128, 1, 1),
-    ((1, 56, 56, 128), 3, 256, 1, 1),
-    ((1, 56, 56, 256), 3, 256, 1, 1),
+    ((1, 7, 7, 512), 3, 512, 1, 1, "SAME"),
+    ((1, 14, 14, 256), 3, 512, 2, 1, "SAME"),
+    ((8, 56, 56, 64), 3, 64, 1, 1, "SAME"),
+    ((1, 224, 224, 3), 5, 64, 2, 1, "SAME"),
+    ((1, 14, 14, 512), 3, 512, 1, 512, "SAME"),
+    ((8, 112, 112, 64), 3, 64, 2, 64, "SAME"),
+    ((32, 224, 224, 3), 5, 64, 2, 1, "SAME"),
+    ((32, 224, 224, 3), 3, 32, 2, 1, "SAME"),
+    ((1, 224, 224, 3), 7, 64, 2, 1, "SAME"),
+    ((1, 55, 55, 32), 3, 128, 1, 1, "SAME"),
+    ((1, 56, 56, 128), 3, 256, 1, 1, "SAME"),
+    ((1, 56, 56, 256), 3, 256, 1, 1, "SAME"),
+    ((32, 7, 7, 512), 7, 4096, 1, 1, "VALID"),
+    ((32, 1, 1, 4096), 1, 4096, 1, 1, "VALID"),
+    ((32, 1, 1, 4096), 1, 1000, 1, 1, "VALID"),
+    ((1, 7, 7, 512), 7, 4096, 1, 1, "VALID"),
+    ((32, 224, 224, 64), 3, 64, 1, 1, "SAME"),
 ]
 
 
-@pytest.mark.parametrize("xshape,K,Cout,stride,groups", CONV_SHAPES,
-                         ids=lambda v: "x".join(map(str, v))
-                         if isinstance(v, tuple) else str(v))
-def test_fused_conv_compiles(one_chip, xshape, K, Cout, stride, groups):
+def _case_id(xshape, K, Cout, stride, groups, padding):
+    parts = ["x".join(map(str, xshape)), K, Cout, stride, groups]
+    return "-".join(map(str, parts + ([padding] if padding != "SAME"
+                                      else [])))
+
+
+@pytest.mark.parametrize("xshape,K,Cout,stride,groups,padding",
+                         [pytest.param(*c, id=_case_id(*c))
+                          for c in CONV_SHAPES])
+def test_fused_conv_compiles(one_chip, xshape, K, Cout, stride, groups,
+                             padding):
     B, H, W, C = xshape
-    kw = dict(stride=stride, padding="SAME", groups=groups)
+    kw = dict(stride=stride, padding=padding, groups=groups)
     key = autotune.conv_key(B, H, W, C, K, Cout, cfg=CONFIG.qcfg,
                             backend="tpu", **kw)
     config = (autotune.lookup(key)
@@ -141,7 +157,8 @@ def test_wkv6_compiles(one_chip):
 
 # net → (conv kernels, the layers that hold them)
 SCOPED_NETS = {"resnet34": (36, r"stem|stages\.\d\.\d\.(c1|c2|proj)"),
-               "mobilenet_v1": (27, r"stem|pairs\.\d+\.(dw|pw)")}
+               "mobilenet_v1": (27, r"stem|pairs\.\d+\.(dw|pw)"),
+               "vgg16": (16, r"convs\.\d+|fcs\.[012]")}
 
 
 @pytest.fixture(scope="module", params=sorted(SCOPED_NETS))
