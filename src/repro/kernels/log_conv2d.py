@@ -69,6 +69,7 @@ per call.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -398,6 +399,13 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
     own padding, which the patches take in.  A ``rows_per_tile`` that does
     not divide ``Ho·B`` pads the patches to whole tiles, a copy: the
     autotuner offers only divisors there.
+
+    Where row tiles overlap (K > 1, ``n_rt·rows_in > Hp``) the kernel
+    reads each window in place, at one row offset for all images of a
+    step, so ``bt`` counts whole images at one row tile.  A
+    ``batch_per_tile`` that spans several row tiles of an image resolves
+    to one taller window: the same rows and grid steps, with ``rt``,
+    ``n_rt`` and ``rows_in`` those of the launch.
     """
     pads = normalize_padding(padding, K, stride, H, W)
     Ho = _out_size(H, K, stride, pads[0])
@@ -437,29 +445,52 @@ def _direct_geometry(B, H, W, C, K, Cout, *, stride, padding, groups,
     else:
         bcin = max(1, min(block_cin, cin_g))
         cin_gp = _ceil_to(cin_g, bcin)
-    rows_in = rt * stride + K - 1          # row tile + halo
     Wp = Wo * stride + K - 1
     Hp = n_rt * rt * stride + K - 1        # rows so every tile's halo exists
     BT = B * n_rt
-    vmem = functools.partial(
-        _fused_vmem_bytes, rt=rt, rows_in=rows_in, Wp=Wp, Wo=Wo, bcin=bcin,
-        bcout=bcout, ow=bcout * g_b, taps=K * K, stride=stride, g_b=g_b)
+
+    def launch(d):
+        """The launch of a tile of ``d`` (image, row tile) pairs.  Where
+        row tiles overlap (K > 1) the kernel reads each step's window in
+        place, at one row offset for all its images: the tile is ``b``
+        whole images at a window of ``m`` consecutive row tiles, with
+        ``m·b = d`` (``m = gcd(d, n_rt)``; then ``b`` divides B)."""
+        m = math.gcd(d, n_rt) if K > 1 else 1
+        r = rt * m
+        rows_in = r * stride + K - 1       # row tile + halo
+        return dict(rt=r, n_rt=n_rt // m, bt=d // m, rows_in=rows_in,
+                    BT=B * (n_rt // m), vmem=_fused_vmem_bytes(
+                        bt=d // m, rt=r, rows_in=rows_in, Wp=Wp, Wo=Wo,
+                        bcin=bcin, bcout=bcout, ow=bcout * g_b, taps=K * K,
+                        stride=stride, g_b=g_b))
+
     if batch_per_tile is None:
         # weight-stationary across batch (the paper's multi-threaded weight
         # broadcast): the widest batch tile whose step fits the VMEM budget
         bt = max((d for d in range(1, BT + 1)
-                  if BT % d == 0 and vmem(bt=d) <= VMEM_BUDGET_BYTES),
+                  if BT % d == 0 and launch(d)["vmem"] <= VMEM_BUDGET_BYTES),
                  default=1)
     else:
         bt = max(1, min(int(batch_per_tile), BT))
         while BT % bt:
             bt -= 1
-    return dict(pads=pads, Ho=Ho, Wo=Wo, cin_g=cin_g, cout_g=cout_g,
-                rt=rt, n_rt=n_rt, bcin=bcin, bcout=bcout, cin_gp=cin_gp,
-                cout_gp=cout_gp, rows_in=rows_in, Wp=Wp, Hp=Hp, BT=BT, bt=bt,
-                ncb=cin_gp // bcin, njb=cout_gp // bcout, taps=K * K,
-                g_b=g_b, cin_lane=cin_lane, n_sb=n_sb, ow=bcout * g_b,
-                vmem=vmem(bt=bt), fold=False)
+    return dict(launch(bt), pads=pads, Ho=Ho, Wo=Wo, cin_g=cin_g,
+                cout_g=cout_g, bcin=bcin, bcout=bcout, cin_gp=cin_gp,
+                cout_gp=cout_gp, Wp=Wp, Hp=Hp, ncb=cin_gp // bcin,
+                njb=cout_gp // bcout, taps=K * K, g_b=g_b,
+                cin_lane=cin_lane, n_sb=n_sb, ow=bcout * g_b, fold=False)
+
+
+def row_tile_layout(g: dict) -> str:
+    """How the row tiles of a launch (`fused_conv_geometry`) reach its
+    kernel: ``single`` (one tile), ``reshape`` (several that do not
+    overlap, K = 1: the padded rows as they lie) or ``overlap_in_place``
+    (each step's window read at its row offset in the padded input)."""
+    if g["n_rt"] == 1:
+        return "single"
+    if g["n_rt"] * g["rows_in"] == g["Hp"]:
+        return "reshape"
+    return "overlap_in_place"
 
 
 def _fused_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
@@ -550,15 +581,18 @@ def log_conv2d_fused_pallas(x, packed, scale,
     the same kernel runs them as a 1×1, stride-1 VALID conv that
     contracts all ``K²·Cin`` lanes in one reduction block, whatever
     ``block_cin`` asks.  Row tiles that do not overlap (K = 1, so every
-    folded launch) are a reshape of the input, not a copy.
+    folded launch) are a reshape of the input, not a copy.  Row tiles
+    that overlap (K > 1) are read in place: the activation goes to the
+    kernel padded and whole, and each step's block is a window of
+    ``rows_in`` rows at an element offset, so no tile is copied in HBM.
 
     The steps around the kernel run under `jax.named_scope`s that name
     their role in the compiled program's metadata: ``fold`` (the
-    patches), ``pad``, ``halo`` (the overlapping row tiles), ``weights``
-    (codes and scales to the kernel's layout) and ``unscramble``.  The
-    `pallas_call` itself is left out of any scope: the innermost scope
-    would name its custom call in place of ``log_conv2d_fused_pallas``,
-    and its target, ``tpu_custom_call``, already says what it is.
+    patches), ``pad``, ``weights`` (codes and scales to the kernel's
+    layout) and ``unscramble``.  The `pallas_call` itself is left out of
+    any scope: the innermost scope would name its custom call in place of
+    ``log_conv2d_fused_pallas``, and its target, ``tpu_custom_call``,
+    already says what it is.
     """
     if prepacked:
         assert lane_pack is not None and lane_pack > 1, \
@@ -614,17 +648,31 @@ def log_conv2d_fused_pallas(x, packed, scale,
             x5 = xp.reshape(B, Hp, Wp, G, cin_g)
             x5 = jnp.pad(x5, ((0, 0),) * 4 + ((0, cin_gp - cin_g),))
             xp = x5.reshape(B, Hp, Wp, G * cin_gp)
-    if n_rt * rows_in == Hp:
+    C_out = n_sb * cout_gp * g_b
+    if row_tile_layout(g) != "overlap_in_place":
         # tiles that do not overlap (one tile, or K = 1 as in every folded
         # launch) are the padded rows as they lie: a reshape, not a copy
-        xrt = xp.reshape(BT, rows_in, Wp, -1)
+        x_in = xp.reshape(BT, rows_in, Wp, -1)
+        x_spec = pl.BlockSpec((bt, rows_in, Wp, bcin),
+                              lambda i, gg, j, c: (i, 0, 0, gg * ncb + c))
+        out_spec = pl.BlockSpec((bt, rt, Wo, ow),
+                                lambda i, gg, j, c: (i, 0, 0, gg * njb + j))
+        out_shape = (BT, rt, Wo, C_out)
     else:
-        # overlapping row tiles: duplicates only the (K-1)-row halo in HBM
-        with jax.named_scope("halo"):
-            tiles = [jax.lax.slice_in_dim(xp, i * rt * stride,
-                                          i * rt * stride + rows_in, axis=1)
-                     for i in range(n_rt)]
-            xrt = jnp.stack(tiles, axis=1).reshape(BT, rows_in, Wp, -1)
+        # overlapping row tiles are read in place: each step's window of
+        # `bt` images at row tile i % n_rt starts at element offsets of xp
+        # (Mosaic takes element offsets only where every dim is an Element,
+        # and can prove a lane offset of 0 only where it is a constant)
+        x_in = xp
+        x_spec = pl.BlockSpec(
+            tuple(map(pl.Element, (bt, rows_in, Wp, bcin))),
+            lambda i, gg, j, c: ((i // n_rt) * bt, (i % n_rt) * rt * stride,
+                                 0, (gg * ncb + c) * bcin
+                                 if n_sb * ncb > 1 else 0))
+        out_spec = pl.BlockSpec(
+            (bt, None, rt, Wo, ow),
+            lambda i, gg, j, c: (i // n_rt, i % n_rt, 0, 0, gg * njb + j))
+        out_shape = (B, n_rt, rt, Wo, C_out)
 
     with jax.named_scope("weights"):
         # codes, still int8 (padding uses code 0, the dedicated zero code):
@@ -657,22 +705,19 @@ def log_conv2d_fused_pallas(x, packed, scale,
                           cin_lane=cin_lane),
         grid=(BT // bt, n_sb, njb, ncb),
         in_specs=[
-            pl.BlockSpec((bt, rows_in, Wp, bcin),
-                         lambda bi, gg, j, c: (bi, 0, 0, gg * ncb + c)),
+            x_spec,
             pl.BlockSpec((1, taps, bcin, bcout),
-                         lambda bi, gg, j, c: (gg, 0, c, j)),
-            pl.BlockSpec((1, 1, ow), lambda bi, gg, j, c: (gg, 0, j)),
+                         lambda i, gg, j, c: (gg, 0, c, j)),
+            pl.BlockSpec((1, 1, ow), lambda i, gg, j, c: (gg, 0, j)),
         ],
-        out_specs=pl.BlockSpec((bt, rt, Wo, ow),
-                               lambda bi, gg, j, c: (bi, 0, 0, gg * njb + j)),
-        out_shape=jax.ShapeDtypeStruct((BT, rt, Wo, n_sb * cout_gp * g_b),
-                                       out_dtype or x.dtype),
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bt * rt * Wo, ow), acc_dtype)],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(xrt, w, s)
+    )(x_in, w, s)
     # unscramble: [.., n_sb, (o, g)] → group-major channels, crop padding
     with jax.named_scope("unscramble"):
         out = out.reshape(B, n_rt * rt, Wo, n_sb, cout_gp, g_b)[:, :Ho]
@@ -697,10 +742,11 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
     """Bytes moved HBM↔VMEM for one conv call, per implementation.
 
     First-order model: counts every block fetch/spill the grid actually
-    performs (patch materialisation write+read, the fused path's halo row
-    tiles and folded patches written and read, per-output-block activation
-    re-reads, per-tile weight re-reads) and ignores sub-block padding
-    waste.
+    performs (patch materialisation write+read, the fused path's folded
+    patches written and read, per-output-block activation re-reads,
+    per-tile weight re-reads) and ignores sub-block padding waste.  The
+    fused kernel reads overlapping row tiles in place, so their halo rows
+    count once per tile that fetches them and are never written.
     Returns ``{"act": ..., "w": ..., "out": ..., "act_w": ..., "total": ...}``;
     fused rows add ``act_kernel``, the part of ``act`` the kernel's own
     grid fetches.  ``"pallas_direct"`` is the fused launch with the taps
@@ -754,11 +800,6 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
                             * act_itemsize * g["njb"])
         w = (g["n_sb"] * g["taps"] * g["ncb"] * _ceil_to(g["bcin"], lanes)
              * g["cout_gp"] * code_itemsize * n_bt)
-        if g["n_rt"] * g["rows_in"] > g["Hp"]:
-            # overlapping row tiles are stacked in HBM first: the padded
-            # input read once, every tile with its halo written once
-            act += ((B * g["Hp"] + g["BT"] * g["rows_in"]) * g["Wp"] * ch
-                    * act_itemsize)
         if g["fold"]:
             # the patches the launch reads are built in HBM first: x read
             # once, the patches written once

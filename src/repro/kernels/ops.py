@@ -43,7 +43,7 @@ from .flash_attention import attention_traffic_bytes, flash_attention_pallas
 from .log_conv2d import (conv_traffic_bytes, fused_conv_geometry,
                          lane_unpack_codes, log_conv2d_blockwise,
                          log_conv2d_fused_pallas, log_conv2d_pallas,
-                         log_conv2d_ref)
+                         log_conv2d_ref, row_tile_layout)
 from .log_matmul import log_matmul_pallas
 from .wkv6 import wkv6_chunked_jnp, wkv6_pallas
 
@@ -294,9 +294,11 @@ def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
             config = explicit
         if prepacked:  # the baked layout forces its own lane_pack factor
             config = dict(config, lane_pack=lane_meta[0])
-        fold = fused_conv_geometry(B, H, W, C, K, Cout, **shape_kw)["fold"]
+        g = fused_conv_geometry(B, H, W, C, K, Cout, **shape_kw, **config)
         _obs_metrics.REGISTRY.counter(
-            "conv_fold", result="folded" if fold else "direct").inc()
+            "conv_fold", result="folded" if g["fold"] else "direct").inc()
+        _obs_metrics.REGISTRY.counter(
+            "conv_row_tiles", layout=row_tile_layout(g)).inc()
         call = lambda: log_conv2d_fused_pallas(x, packed, qt.scale, qt.cfg,
                                                interpret=interp,
                                                prepacked=prepacked, **kw,

@@ -160,6 +160,34 @@ def test_traced_forward_counts_one_fold(name, n_convs):
     assert [a - b for a, b in zip(after, before)] == [1, n_convs - 1]
 
 
+@pytest.mark.parametrize("name,n_overlap,n_convs", [("resnet34", 6, 36),
+                                                   ("mobilenet_v1", 4, 27),
+                                                   ("vgg16", 6, 16)])
+def test_traced_forward_reads_overlapping_row_tiles_in_place(name, n_overlap,
+                                                             n_convs):
+    """At batch 32 and 224 px the convs whose row tiles overlap (ResNet-34's
+    stage 0, MobileNet v1's depthwise convs at 112² and 56², VGG-16's
+    `convs.1`–`convs.6`) read them in place: a traced forward counts each
+    once under `conv_row_tiles{layout="overlap_in_place"}`."""
+    init, apply = CNNS[name]
+    layouts = ("overlap_in_place", "reshape", "single")
+
+    def counts():
+        return [obs_metrics.REGISTRY.counter("conv_row_tiles",
+                                             layout=k).value
+                for k in layouts]
+
+    def forward(key, x):
+        return apply(quantize_cnn_params(init(key), CONFIG.qcfg), x,
+                     conv_impl="pallas")
+
+    before = counts()
+    jax.eval_shape(forward, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                   jax.ShapeDtypeStruct((32, 224, 224, 3), jnp.float32))
+    delta = [a - b for a, b in zip(counts(), before)]
+    assert delta[0] == n_overlap and sum(delta) == n_convs
+
+
 def test_vgg16_has_the_published_parameter_count():
     """Simonyan & Zisserman 2015, Table 2: 138M for config D; 89% of it is
     FC6, the 7×7 conv from 512 to 4096 channels."""

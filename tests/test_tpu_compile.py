@@ -25,7 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.neuromax_cnn import CONFIG
 from repro.kernels import autotune
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.log_conv2d import log_conv2d_fused_pallas
+from repro.kernels.log_conv2d import (fused_conv_geometry,
+                                      log_conv2d_fused_pallas)
 from repro.kernels.log_matmul import log_matmul_pallas
 from repro.kernels.wkv6 import wkv6_pallas
 from repro.models.cnn import make_cnn
@@ -63,6 +64,7 @@ def _compile(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
+    return text
 
 
 # (x shape, K, Cout, stride, groups, padding): the two shapes Mosaic
@@ -75,7 +77,9 @@ def _compile(fn, one_chip, *shapes):
 # classifier as dense-evaluation convs (FC6 a VALID 7×7 conv whose 102.8 MB
 # of codes dwarf its 3.2 MB input, FC7 and FC8 1×1 convs over one pixel;
 # FC6 at batch 1 too, with the packaged table's config) and `convs.1`, the
-# 3×3 conv on the zoo's largest maps
+# 3×3 conv on the zoo's largest maps; and the other batch-32 convs whose
+# row tiles overlap and are read in place: ResNet-34's stage 0 and
+# MobileNet v1's depthwise layers at 112² (stride 1 and 2)
 CONV_SHAPES = [
     ((1, 7, 7, 512), 3, 512, 1, 1, "SAME"),
     ((1, 14, 14, 256), 3, 512, 2, 1, "SAME"),
@@ -94,6 +98,9 @@ CONV_SHAPES = [
     ((32, 1, 1, 4096), 1, 1000, 1, 1, "VALID"),
     ((1, 7, 7, 512), 7, 4096, 1, 1, "VALID"),
     ((32, 224, 224, 64), 3, 64, 1, 1, "SAME"),
+    ((32, 56, 56, 64), 3, 64, 1, 1, "SAME"),
+    ((32, 112, 112, 32), 3, 32, 1, 32, "SAME"),
+    ((32, 112, 112, 64), 3, 64, 2, 64, "SAME"),
 ]
 
 
@@ -122,6 +129,28 @@ def test_fused_conv_compiles(one_chip, xshape, K, Cout, stride, groups,
     _compile(fn, one_chip, (xshape, jnp.float32),
              ((K, K, C // groups, Cout), jnp.int8),
              ((1, 1, 1, Cout), jnp.float32))
+
+
+def test_overlapping_row_tiles_are_read_in_place(one_chip):
+    """VGG-16's `convs.1` at batch 32 has 28 overlapping row tiles per
+    image.  The kernel reads them out of the padded input as the pad
+    writes it: its first operand is the pad, with no copy between, and no
+    instruction builds the tiles (the ``halo`` scope of the stack that
+    did)."""
+    B, H, W, C, K, Cout = 32, 224, 224, 64, 3, 64
+    config = autotune.default_config(B, H, W, C, K, Cout)
+    g = fused_conv_geometry(B, H, W, C, K, Cout, **config)
+    assert g["n_rt"] * g["rows_in"] > g["Hp"]
+    fn = functools.partial(log_conv2d_fused_pallas, cfg=CONFIG.qcfg,
+                           interpret=False, **config)
+    text = _compile(fn, one_chip, ((B, H, W, C), jnp.float32),
+                    ((K, K, C, Cout), jnp.int8), ((1, 1, 1, Cout), jnp.float32))
+    instrs = scopes.entry_instructions(text)
+    assert not [name for name, _, op_name in instrs if "/halo/" in op_name]
+    op_names = {name: op_name for name, _, op_name in instrs}
+    kernel = next(hlo for _, hlo, _ in instrs if scopes.KERNEL_TARGET in hlo)
+    x_in = re.search(r"custom-call\(%([^\s,()]+)", kernel).group(1)
+    assert "/pad/" in op_names[x_in], (x_in, op_names[x_in])
 
 
 def test_log_matmul_compiles(one_chip):
