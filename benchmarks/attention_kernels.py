@@ -9,8 +9,8 @@ full-softmax reference on small shapes, and runs Pallas probes
 blockwise) — as correctness checks.  Emits ``BENCH_attention.json`` at the
 repo root via `benchmarks/common.py`.
 
-Timing hygiene matches `conv_kernels.py`: jitted entry points hoisted to
-module level, compile reported separately from the steady-state mean.
+Timing hygiene: jitted entry points hoisted to module level, compile
+reported separately from the steady-state mean.
 
 Each row carries the analytic HBM traffic per path
 (`kernels/flash_attention.attention_traffic_bytes`).  On CPU the timings

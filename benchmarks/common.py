@@ -15,8 +15,8 @@ def timed(fn):
 
 
 def write_json(filename: str, payload: dict) -> str:
-    """Persist a benchmark's result dict (e.g. ``BENCH_conv.json``) at the
-    repo root so runs are diffable across PRs.  When a previous run exists,
+    """Persist a benchmark's result dict (e.g. ``BENCH_attention.json``) at
+    the repo root so runs are diffable across PRs.  When a previous run exists,
     prints a per-row timing delta table (flagging >1.3× slowdowns) before
     overwriting.  Returns the path written."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

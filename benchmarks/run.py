@@ -13,14 +13,13 @@ import sys
 
 from repro.runtime.compile_cache import setup_compile_cache
 
-from . import (ablation_grad_compress, attention_kernels, conv_kernels,
-               fig1_quant, fig17_pe_cost, fig19_utilization, fig20_throughput,
+from . import (ablation_grad_compress, attention_kernels, fig1_quant,
+               fig17_pe_cost, fig19_utilization, fig20_throughput,
                table2_comparison, table3_latency, telemetry_overhead)
 from .common import timed
 
 BENCHES = {
     "fig1_quant": (fig1_quant, "snr_gain_db"),
-    "conv_kernels": (conv_kernels, "mean_blockwise_overhead_x"),
     "attention_kernels": (attention_kernels, "min_gqa4_traffic_win_x"),
     "fig17_pe_cost": (fig17_pe_cost, "tput_per_pe"),
     "fig19_utilization": (fig19_utilization, None),
@@ -32,8 +31,7 @@ BENCHES = {
 }
 
 
-ALIASES = {"conv": "conv_kernels",  # short names accepted by --only
-           "attention": "attention_kernels",
+ALIASES = {"attention": "attention_kernels",  # short names for --only
            "telemetry": "telemetry_overhead"}
 
 

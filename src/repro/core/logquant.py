@@ -34,7 +34,6 @@ __all__ = [
     "fake_log_quant",
     "linear_quantize",
     "quantize_tensor",
-    "dequantize_tensor",
     "QuantizedTensor",
 ]
 
@@ -172,62 +171,33 @@ def linear_quantize(x: jnp.ndarray, int_bits: int, frac_bits: int):
 class QuantizedTensor:
     """A log-quantized array: int8 packed codes + fp scale (+ static cfg).
 
-    ``layout`` is a storage hint for consumers: ``None`` means ``packed``
-    has the natural layout of ``shape``; ``"conv_taps"`` means a conv
-    kernel pre-reshaped to tap-major ``[K*K, Cin_g, Cout]`` at load time
-    (what the fused Pallas conv kernel streams); ``"lane_packed"`` means a
-    grouped-conv kernel pre-arranged into 128-lane superblocks
-    ``[n_sb, K*K, G_b*cin_lane, Cout//groups]`` with ``layout_meta =
-    (G_b, cin_lane, groups)`` carrying the group-to-lane map (see
-    `kernels/log_conv2d.lane_pack_codes`).  `ops.conv2d` accepts all
-    three.
-    """
+    ``packed`` has the natural layout of ``shape`` (a conv kernel is HWIO
+    ``[K, K, Cin_g, Cout]``); kernels arrange the codes themselves."""
 
     def __init__(self, packed, scale, cfg: LogQuantConfig = DEFAULT,
-                 shape=None, layout: str | None = None,
-                 layout_meta: tuple | None = None):
+                 shape=None):
         self.packed = packed
         self.scale = scale
         self.cfg = cfg
         self.shape = shape if shape is not None else packed.shape
-        self.layout = layout
-        self.layout_meta = layout_meta
 
     def dequantize(self, dtype=jnp.bfloat16):
-        if self.layout == "lane_packed":
-            # layout transforms live with the kernels; import lazily so
-            # core stays import-light (no cycle: kernels import core at
-            # module scope, core reaches back only inside this method).
-            from repro.kernels.log_conv2d import lane_unpack_codes
-            g_b, cin_lane, groups = self.layout_meta
-            codes = lane_unpack_codes(self.packed, self.shape, groups,
-                                      g_b, cin_lane)
-            return log_dequantize(codes, self.scale, self.cfg, dtype=dtype)
-        out = log_dequantize(self.packed, self.scale, self.cfg, dtype=dtype)
-        return out.reshape(self.shape) if self.layout == "conv_taps" else out
+        return log_dequantize(self.packed, self.scale, self.cfg, dtype=dtype)
 
     def tree_flatten(self):
-        return (self.packed, self.scale), (self.cfg, self.shape, self.layout,
-                                           self.layout_meta)
+        return (self.packed, self.scale), (self.cfg, self.shape)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        packed, scale = children
-        cfg, shape, layout, meta = (*aux, *((None,) * (4 - len(aux))))
-        return cls(packed, scale, cfg, shape, layout, meta)
+        return cls(*children, *aux)
 
     def __repr__(self):
-        lay = f", layout={self.layout!r}" if self.layout else ""
-        return f"QuantizedTensor(shape={self.shape}, cfg={self.cfg}{lay})"
+        return f"QuantizedTensor(shape={self.shape}, cfg={self.cfg})"
 
 
 def quantize_tensor(x, cfg: LogQuantConfig = DEFAULT) -> QuantizedTensor:
     packed, scale = log_quantize(x, cfg)
     return QuantizedTensor(packed, scale, cfg, x.shape)
-
-
-def dequantize_tensor(q: QuantizedTensor, dtype=jnp.bfloat16):
-    return q.dequantize(dtype)
 
 
 def quantization_snr_db(x, xq):

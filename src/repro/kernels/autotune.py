@@ -39,7 +39,9 @@ so first inference should never pay the sweep):
 `ops.conv2d(impl="pallas")` and `ops.attention(impl="pallas")` consult
 the tiers in that order on every call and fall back to
 `default_config` / `default_attention_config` heuristics on a miss —
-tuning itself only runs when explicitly requested (``autotune=True``).
+a call never tunes: `autotune_conv2d` / `autotune_attention` run the
+sweep for one shape and `record` its winner (`tools/build_autotune_table.py`
+drives them).
 The `autotune_lookup` counter splits `hit_user`/`hit_warm`/`miss` per
 op, so ``--metrics`` shows how effective the warm-start tier is.
 """

@@ -7,7 +7,7 @@ dataflow on TPU, and the middle of the repo's three-tier conv stack:
         ↕  numerics cross-checked in tests/test_conv2d.py
     core/pe_grid.py        (cycle-accurate 6×3×6 PE-grid hardware oracle)
 
-Four implementations share one contract (see `kernels/ops.conv2d` for the
+Three implementations share one contract (see `kernels/ops.conv2d` for the
 dispatch layer):
 
   * ``log_conv2d_fused_pallas`` — direct NHWC conv: patch extraction
@@ -30,12 +30,6 @@ dispatch layer):
     kernel's `DOT_PRECISION`.  Unfolded, each (8, 128) tile of its input
     would carry 3 useful lanes of 128, and each of the K² taps would
     contract 3 lanes.
-  * ``log_conv2d_pallas`` — the explicit-im2col fallback: patches are
-    materialised in HBM and tiled onto the `log_matmul_pallas` MXU kernel
-    (grouped convs as a block-diagonal code matrix whose out-of-group
-    entries hold the dedicated zero code).  K²× activation traffic, kept
-    as `impl="pallas_im2col"` for cross-checking and as the known-good
-    lowering.
   * ``log_conv2d_blockwise`` — decode-then-`lax.conv` in jnp.  XLA fuses the
     int8→float decode into the convolution's weight operand, so the weight
     bytes that move stay int8 (same memory behaviour as the kernel); this
@@ -44,14 +38,14 @@ dispatch layer):
     patches against `ref.ref_log_matmul` at highest precision.  Independent
     of `lax.conv`, so it cross-validates the patch extraction itself.
 
-All four take the same packed layout: ``packed [K, K, Cin//groups, Cout]``
+All three take the same packed layout: ``packed [K, K, Cin//groups, Cout]``
 int8 codes with a per-output-channel (or scalar) fp scale, `stride`,
-`padding` ("SAME"/"VALID"/int/explicit pairs) and `groups`.
-`conv_traffic_bytes` is the shared analytic HBM-traffic model the conv
-benchmark reports per impl.
+`padding` ("SAME"/"VALID"/int/explicit pairs) and `groups`; the fused
+kernel arranges the codes for its launch itself, under its ``weights``
+scope.  `conv_traffic_bytes` is the shared analytic HBM-traffic model.
 
-Grouped/depthwise convs additionally support a **lane-packed** layout on
-the fused kernel (see `lane_pack_geometry`): on real TPUs the MXU/VPU
+Grouped/depthwise convs are additionally **lane-packed** inside the
+fused kernel (see `lane_pack_geometry`): on real TPUs the MXU/VPU
 lane dimension is 128 wide, so a contraction over one group's `cin_g`
 channels occupies a full 128-lane block no matter how narrow the group —
 at depthwise `cin_g = 1` that is 1/128 lane density.  Lane packing
@@ -61,9 +55,8 @@ contracts `G_b` groups at once; the compact codes are **unpacked next to
 the MXU** by an in-kernel masked broadcast (lane `l` serves group
 ``l // cin_lane``; out-of-group taps multiply by an exact 0), so HBM
 weight traffic stays compact — no block-diagonal expansion ever leaves
-VMEM.  `serving/quantize.quantize_cnn_params(conv_layout="lane_packed")`
-bakes the layout at load time; `ops.ConvConfig(lane_pack=...)` selects it
-per call.
+VMEM.  `ops.ConvConfig(lane_pack=...)`, the autotune table or the
+heuristic selects the packing per call.
 """
 
 from __future__ import annotations
@@ -77,7 +70,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.logquant import LogQuantConfig, log_dequantize
-from .log_matmul import _decode_block, log_matmul_pallas
+from .log_matmul import _decode_block
 from .ref import ref_log_matmul
 
 DEFAULT_CFG = LogQuantConfig()
@@ -193,24 +186,8 @@ def _check_shapes(x, packed, groups):
 
 
 # ---------------------------------------------------------------------------
-# the three implementations
+# the blockwise fallback
 # ---------------------------------------------------------------------------
-
-
-def log_conv2d_pallas(x, packed, scale, cfg: LogQuantConfig = DEFAULT_CFG,
-                      *, stride: int = 1, padding="SAME", groups: int = 1,
-                      interpret: bool = False, out_dtype=None):
-    """Packed-weight conv on the `log_matmul_pallas` MXU path via im2col."""
-    B, H, W, C, K, Cout = _check_shapes(x, packed, groups)
-    pads = normalize_padding(padding, K, stride, H, W)
-    patches, Ho, Wo = _im2col(x, K, stride, pads)
-    codes = _block_diag_codes(packed, groups)
-    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(1, -1),
-                             (1, Cout))
-    out = log_matmul_pallas(patches.reshape(B * Ho * Wo, -1), codes, scale,
-                            cfg, interpret=interpret,
-                            out_dtype=out_dtype or x.dtype)
-    return out.reshape(B, Ho, Wo, Cout)
 
 
 def log_conv2d_blockwise(x, packed, scale, cfg: LogQuantConfig = DEFAULT_CFG,
@@ -290,19 +267,6 @@ def lane_pack_codes(packed, groups: int, g_b: int, cin_lane: int):
     w = w.transpose(2, 0, 1, 3).reshape(n_sb, g_b, taps, cin_lane, cout_g)
     return w.transpose(0, 2, 1, 3, 4).reshape(n_sb, taps, g_b * cin_lane,
                                               cout_g)
-
-
-def lane_unpack_codes(packed_lp, shape, groups: int, g_b: int,
-                      cin_lane: int):
-    """Inverse of `lane_pack_codes`: → the natural [K, K, cin_g, Cout]."""
-    K1, K2, cin_g, Cout = shape
-    taps, cout_g = K1 * K2, Cout // groups
-    n_sb = packed_lp.shape[0]
-    w = packed_lp.reshape(n_sb, taps, g_b, cin_lane, cout_g)
-    w = w.transpose(0, 2, 1, 3, 4).reshape(n_sb * g_b, taps, cin_lane,
-                                           cout_g)
-    w = w[:groups, :, :cin_g, :]
-    return w.transpose(1, 2, 0, 3).reshape(K1, K2, cin_g, Cout)
 
 
 # ---------------------------------------------------------------------------
@@ -411,28 +375,12 @@ def fused_conv_geometry(B: int, H: int, W: int, C: int, K: int, Cout: int,
     Ho = _out_size(H, K, stride, pads[0])
     Wo = _out_size(W, K, stride, pads[1])
     if _fold_pays(H, W, C, K, groups, pads, Ho, Wo):
-        g = _direct_geometry(
-            1, Ho * B, Wo, K * K * C, 1, Cout, stride=1, padding="VALID",
-            groups=1, block_cin=K * K * C, block_cout=block_cout,
-            rows_per_tile=rows_per_tile, batch_per_tile=batch_per_tile,
-            lane_pack=None)
+        # the folded launch: a 1×1 conv (K = 1 never folds again)
+        g = fused_conv_geometry(
+            1, Ho * B, Wo, K * K * C, 1, Cout, padding="VALID",
+            block_cin=K * K * C, block_cout=block_cout,
+            rows_per_tile=rows_per_tile, batch_per_tile=batch_per_tile)
         return dict(g, fold=True, fold_pads=pads)
-    return _direct_geometry(B, H, W, C, K, Cout, stride=stride,
-                            padding=padding, groups=groups,
-                            block_cin=block_cin, block_cout=block_cout,
-                            rows_per_tile=rows_per_tile,
-                            batch_per_tile=batch_per_tile,
-                            lane_pack=lane_pack)
-
-
-def _direct_geometry(B, H, W, C, K, Cout, *, stride, padding, groups,
-                     block_cin, block_cout, rows_per_tile, batch_per_tile,
-                     lane_pack) -> dict:
-    """`fused_conv_geometry` of the launch with the taps as they are: the
-    conv's own, or a folded conv's 1×1 launch over its patches."""
-    pads = normalize_padding(padding, K, stride, H, W)
-    Ho = _out_size(H, K, stride, pads[0])
-    Wo = _out_size(W, K, stride, pads[1])
     cin_g, cout_g = C // groups, Cout // groups
     lp = lane_pack_geometry(groups, cin_g, lane_pack)
     g_b, cin_lane, n_sb = lp["g_b"], lp["cin_lane"], lp["n_sb"]
@@ -540,7 +488,7 @@ def _fused_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=(
     "cfg", "stride", "padding", "groups", "interpret", "out_dtype",
     "block_cin", "block_cout", "rows_per_tile", "batch_per_tile",
-    "lane_pack", "prepacked"))
+    "lane_pack"))
 def log_conv2d_fused_pallas(x, packed, scale,
                             cfg: LogQuantConfig = DEFAULT_CFG, *,
                             stride: int = 1, padding="SAME", groups: int = 1,
@@ -548,8 +496,7 @@ def log_conv2d_fused_pallas(x, packed, scale,
                             block_cin: int | None = 128, block_cout: int = 128,
                             rows_per_tile: int | None = None,
                             batch_per_tile: int | None = None,
-                            lane_pack: int | None = None,
-                            prepacked: bool = False):
+                            lane_pack: int | None = None):
     """Direct NHWC conv with VMEM patch extraction (implicit im2col).
 
     Grid: (batch·row tiles, group superblocks, cout blocks, cin blocks)
@@ -568,10 +515,9 @@ def log_conv2d_fused_pallas(x, packed, scale,
     taps contract as exact zeros), and each MXU pass produces ``g_b``
     groups' outputs — recovering up to 128× lane density for depthwise
     convs on real TPUs.  ``None`` auto-packs grouped shapes; ``1``
-    forces the padded per-group path.  ``prepacked=True`` means `packed`
-    is already in the `lane_pack_codes` layout
-    ``[n_sb, K*K, g_b*cin_lane, cout_g]`` (the `QuantizedTensor`
-    ``"lane_packed"`` serving layout), skipping the per-call rearrange.
+    forces the padded per-group path.  `packed` is always the natural
+    HWIO codes; the ``weights`` scope arranges them (`lane_pack_codes`,
+    or padded per group) for the launch.
 
     A dense conv folds its taps into channels where its input channels
     are too few to fill the lanes (`_fold_pays`, decided from the shape
@@ -594,16 +540,7 @@ def log_conv2d_fused_pallas(x, packed, scale,
     ``log_conv2d_fused_pallas``, and its target, ``tpu_custom_call``,
     already says what it is.
     """
-    if prepacked:
-        assert lane_pack is not None and lane_pack > 1, \
-            "prepacked codes require the matching lane_pack factor"
-        B, H, W, C = x.shape
-        K = int(round(packed.shape[1] ** 0.5))
-        cout_g = packed.shape[-1]
-        Cout = groups * cout_g
-        assert C % groups == 0, (x.shape, groups)
-    else:
-        B, H, W, C, K, Cout = _check_shapes(x, packed, groups)
+    B, H, W, C, K, Cout = _check_shapes(x, packed, groups)
     g = fused_conv_geometry(
         B, H, W, C, K, Cout, stride=stride, padding=padding, groups=groups,
         block_cin=block_cin, block_cout=block_cout,
@@ -625,10 +562,6 @@ def log_conv2d_fused_pallas(x, packed, scale,
     bcin, bcout, ncb, njb = g["bcin"], g["bcout"], g["ncb"], g["njb"]
     rows_in, Wp, Hp, BT = g["rows_in"], g["Wp"], g["Hp"], g["BT"]
     g_b, cin_lane, n_sb, ow = g["g_b"], g["cin_lane"], g["n_sb"], g["ow"]
-    if prepacked:
-        assert g_b == lane_pack and packed.shape == (n_sb, taps,
-                                                     g_b * cin_lane, cout_g), \
-            (packed.shape, (n_sb, taps, g_b * cin_lane, cout_g))
 
     # pad lead edges, then fit the trailing edge to the tiled extent (extra
     # zero rows/cols are only read into discarded stride phases)
@@ -679,8 +612,7 @@ def log_conv2d_fused_pallas(x, packed, scale,
         #   padded path:      [K, K, cin_g, Cout] → [G, taps, cin_gp, cout_gp]
         #   lane-packed path: `lane_pack_codes` → [n_sb, taps, Lc, cout_gp]
         if g_b > 1:
-            w = packed if prepacked else lane_pack_codes(packed, G, g_b,
-                                                         cin_lane)
+            w = lane_pack_codes(packed, G, g_b, cin_lane)
             w = jnp.pad(w, ((0, 0),) * 3 + ((0, cout_gp - cout_g),))
         else:
             w = packed.reshape(taps, cin_g, G, cout_g)
@@ -730,7 +662,8 @@ def log_conv2d_fused_pallas(x, packed, scale,
 
 
 # ---------------------------------------------------------------------------
-# analytic HBM-traffic model (reported per impl by benchmarks/conv_kernels)
+# analytic HBM-traffic model (the autotune table builder ranks by it; the
+# kernel-dispatch profiler reports it per call)
 # ---------------------------------------------------------------------------
 
 
@@ -738,31 +671,27 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
                        Cout: int, *, stride: int = 1, padding="SAME",
                        groups: int = 1, act_itemsize: int = 4,
                        code_itemsize: int = 1, config: dict | None = None,
-                       matmul_block: int = 128, lanes: int = 1) -> dict:
-    """Bytes moved HBM↔VMEM for one conv call, per implementation.
+                       lanes: int = 1) -> dict:
+    """Bytes moved HBM↔VMEM for one conv call, per implementation
+    (``"fp32"``, ``"blockwise"`` or ``"pallas"``).
 
     First-order model: counts every block fetch/spill the grid actually
-    performs (patch materialisation write+read, the fused path's folded
-    patches written and read, per-output-block activation re-reads,
-    per-tile weight re-reads) and ignores sub-block padding waste.  The
-    fused kernel reads overlapping row tiles in place, so their halo rows
-    count once per tile that fetches them and are never written.
-    Returns ``{"act": ..., "w": ..., "out": ..., "act_w": ..., "total": ...}``;
-    fused rows add ``act_kernel``, the part of ``act`` the kernel's own
-    grid fetches.  ``"pallas_direct"`` is the fused launch with the taps
-    left unfolded whatever `_fold_pays` decides: what a fold is judged
-    against.
+    performs (the fused path's folded patches written and read,
+    per-output-block activation re-reads, per-tile weight re-reads) and
+    ignores sub-block padding waste.  The fused kernel reads overlapping
+    row tiles in place, so their halo rows count once per tile that
+    fetches them and are never written.
+    Returns ``{"act": ..., "w": ..., "out": ..., "act_w": ..., "total": ...}``.
 
     ``lanes`` models the physical lane width of the fused path's channel
     blocks: a real TPU DMAs (and contracts) whole 128-lane blocks, so a
     grouped conv's per-group `cin` block costs ``ceil_to(bcin, lanes)``
     channels no matter how narrow the group.  The default ``lanes=1`` is
-    the pure byte count (backend-independent, what the 3×3 acceptance
-    gates); ``lanes=128`` is the hardware-honest figure the lane-packed
-    bench rows compare.  Fused rows also carry ``lane_density`` — useful
-    contraction lanes over fetched 128-lane capacity, the utilization the
-    lane-packed layout recovers (reported per dispatch by
-    `obs/kernel_profile.py`).
+    the pure byte count (backend-independent); ``lanes=128`` is the
+    hardware-honest figure the autotune table builder ranks by.  Fused
+    rows also carry ``lane_density`` — useful contraction lanes over
+    fetched 128-lane capacity, the utilization lane packing recovers
+    (reported per dispatch by `obs/kernel_profile.py`).
     """
     pads = normalize_padding(padding, K, stride, H, W)
     Ho, Wo = _out_size(H, K, stride, pads[0]), _out_size(W, K, stride, pads[1])
@@ -776,28 +705,17 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
         act, w = x_b, K * K * cin_g * Cout * act_itemsize
     elif impl == "blockwise":
         act, w = x_b, w_codes
-    elif impl == "pallas_im2col":
-        # patches hit HBM: K² tap-slice reads of x, one write, then one read
-        # per output-channel block of the matmul; weights are block-diagonal
-        # (×groups) and re-read per M block.
-        patch_b = B * Ho * Wo * K * K * C * act_itemsize
-        n_j = -(-Cout // matmul_block)
-        n_i = -(-(B * Ho * Wo) // matmul_block)
-        act = patch_b * (2 + n_j)
-        w = K * K * groups * cin_g * Cout * code_itemsize * n_i
-    elif impl in ("pallas", "pallas_fused", "pallas_direct"):
+    elif impl == "pallas":
         cfg = {**dict(block_cin=128, block_cout=128, rows_per_tile=None,
                       batch_per_tile=None, lane_pack=None), **(config or {})}
-        geometry = (_direct_geometry if impl == "pallas_direct"
-                    else fused_conv_geometry)
-        g = geometry(B, H, W, C, K, Cout, stride=stride, padding=padding,
-                     groups=groups, **cfg)
+        g = fused_conv_geometry(B, H, W, C, K, Cout, stride=stride,
+                                padding=padding, groups=groups, **cfg)
         n_bt = g["BT"] // g["bt"]
         # fetched channel width per (superblock, reduction step), padded to
         # whole physical lane blocks; g_b=1 ⇒ n_sb=groups, bcin·ncb=cin_gp
         ch = g["n_sb"] * g["ncb"] * _ceil_to(g["bcin"], lanes)
-        act = act_kernel = (n_bt * g["bt"] * g["rows_in"] * g["Wp"] * ch
-                            * act_itemsize * g["njb"])
+        act = (n_bt * g["bt"] * g["rows_in"] * g["Wp"] * ch * act_itemsize
+               * g["njb"])
         w = (g["n_sb"] * g["taps"] * g["ncb"] * _ceil_to(g["bcin"], lanes)
              * g["cout_gp"] * code_itemsize * n_bt)
         if g["fold"]:
@@ -814,7 +732,6 @@ def conv_traffic_bytes(impl: str, B: int, H: int, W: int, C: int, K: int,
            "act_w": int(act + w), "total": int(act + w + out_b)}
     if density is not None:
         out["lane_density"] = round(min(density, 1.0), 4)
-        out["act_kernel"] = int(act_kernel)
     return out
 
 

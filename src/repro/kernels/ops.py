@@ -3,8 +3,7 @@
 The unified kernel-call surface.  Every public op takes the same trio of
 dispatch knobs, resolved by `resolve_impl` with one precedence order:
 
-  impl=       "pallas" | "blockwise" | "ref" | "auto" (+ op-specific
-              aliases, e.g. conv2d's "pallas_im2col").  "auto" → pallas
+  impl=       "pallas" | "blockwise" | "ref" | "auto".  "auto" → pallas
               on TPU, blockwise elsewhere.
   config=     a per-op frozen config dataclass (`AttentionConfig`,
               `ConvConfig`, `WkvConfig`) holding block sizes / math
@@ -14,8 +13,9 @@ dispatch knobs, resolved by `resolve_impl` with one precedence order:
   interpret=  None → interpret off-TPU (so Pallas kernels run anywhere);
               an explicit bool always wins.
 
-Plus ``autotune=True`` on the tiled kernels (conv2d, attention) to
-measure candidates for the call's shape first and persist the winner.
+Tuning sweeps are not part of a call: `autotune.autotune_conv2d` /
+`autotune_attention` (driven by `tools/build_autotune_table.py`) measure
+a shape's candidates and persist the winner, which later calls look up.
 
 Implementations per op:
   "pallas"    — the Pallas kernel (TPU; `interpret=True` executes on CPU)
@@ -41,8 +41,7 @@ from . import autotune as _autotune
 from . import ref as _ref
 from .flash_attention import attention_traffic_bytes, flash_attention_pallas
 from .log_conv2d import (conv_traffic_bytes, fused_conv_geometry,
-                         lane_unpack_codes, log_conv2d_blockwise,
-                         log_conv2d_fused_pallas, log_conv2d_pallas,
+                         log_conv2d_blockwise, log_conv2d_fused_pallas,
                          log_conv2d_ref, row_tile_layout)
 from .log_matmul import log_matmul_pallas
 from .wkv6 import wkv6_chunked_jnp, wkv6_pallas
@@ -56,7 +55,7 @@ def _on_tpu() -> bool:
 
 _OP_IMPLS = {
     "log_matmul": ("pallas", "blockwise", "ref"),
-    "conv2d": ("pallas", "pallas_im2col", "blockwise", "ref"),
+    "conv2d": ("pallas", "blockwise", "ref"),
     "attention": ("pallas", "blockwise", "ref"),
     "wkv6": ("pallas", "blockwise", "ref"),
 }
@@ -108,9 +107,8 @@ class ConvConfig:
     `log_conv2d.lane_pack_geometry`): ``None`` auto-packs narrow groups
     into shared 128-lane blocks, ``1`` forces the padded per-group path,
     ``n ≥ 2`` packs up to ``n`` groups per block.  Precedence: an
-    explicit value here beats a `QuantizedTensor`'s baked-in
-    ``"lane_packed"`` layout (which is unpacked if they disagree), which
-    beats the autotune table, which beats the auto heuristic."""
+    explicit value here beats the autotune table, which beats the auto
+    heuristic."""
     block_cin: int | None = None
     block_cout: int | None = None
     rows_per_tile: int | None = None
@@ -135,15 +133,6 @@ def _conv_config_dict(config) -> dict | None:
 
 
 _CONV_CONFIG_FIELDS = tuple(f.name for f in dataclasses.fields(ConvConfig))
-
-_WARNED_ONCE: set[str] = set()  # one-shot UserWarning dedupe, per process
-
-
-def _warn_once(msg: str) -> None:
-    if msg not in _WARNED_ONCE:
-        _WARNED_ONCE.add(msg)
-        warnings.warn(msg, UserWarning, stacklevel=3)
-
 
 def _itemsize(x) -> int:
     try:
@@ -207,21 +196,18 @@ def _hashable_padding(padding):
 def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
            impl: str = "auto", interpret: bool | None = None,
            out_dtype=None, qcfg: LogQuantConfig | None = None,
-           config: ConvConfig | dict | None = None, autotune: bool = False):
+           config: ConvConfig | dict | None = None):
     """x: [B, H, W, Cin] ⊛ dequant(qt [K, K, Cin//groups, Cout]) → NHWC out.
 
     The single entry point of the three-tier conv stack (see
     `kernels/log_conv2d.py`): ``impl="pallas"`` is the fused
     implicit-im2col kernel (block sizes from the autotuner's on-disk table
     when present, heuristics otherwise; ``config=`` — a `ConvConfig` or
-    plain dict — overrides, ``autotune=True`` measures candidates for
-    this shape first and persists the winner), ``"pallas_im2col"`` the
-    explicit-im2col fallback on `log_matmul_pallas`, ``"blockwise"`` the
-    jnp fallback, ``"ref"`` the full-materialisation oracle; `auto` means
-    pallas on TPU and blockwise elsewhere.  `qt` is a `QuantizedTensor`
-    of packed log codes (per-output-channel scales supported; the
-    serving-time ``layout="conv_taps"`` pre-reshape is accepted); a plain
-    float array is packed on the fly as a convenience (inference only —
+    plain dict — overrides), ``"blockwise"`` the jnp fallback, ``"ref"``
+    the full-materialisation oracle; `auto` means pallas on TPU and
+    blockwise elsewhere.  `qt` is a `QuantizedTensor` of packed log codes
+    in HWIO layout (per-output-channel scales supported); a plain float
+    array is packed on the fly as a convenience (inference only —
     quantization is not differentiable).  Supports stride,
     SAME/VALID/explicit padding, and grouped/depthwise convs
     (``groups=Cin``).
@@ -229,12 +215,6 @@ def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
     if not isinstance(qt, QuantizedTensor):
         qt = quantize_tensor(jnp.asarray(qt), qcfg or LogQuantConfig())
     packed = qt.packed
-    layout = getattr(qt, "layout", None)
-    lane_meta = None
-    if layout == "conv_taps":
-        packed = packed.reshape(qt.shape)  # [taps, cin_g, Cout] → 4-D HWIO
-    elif layout == "lane_packed":
-        lane_meta = tuple(qt.layout_meta)  # (g_b, cin_lane, groups)
     assert packed.ndim == 4, f"conv weights must be [K,K,Cin_g,Cout], " \
         f"got {packed.shape}"
     impl, interp = resolve_impl("conv2d", impl, interpret)
@@ -243,44 +223,11 @@ def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
     kw = dict(stride=stride, padding=padding, groups=groups,
               out_dtype=out_dtype)
     B, H, W, C = x.shape
-    hwio = tuple(qt.shape) if lane_meta is not None else packed.shape
-    K, Cout = hwio[0], hwio[-1]
+    K, Cout = packed.shape[0], packed.shape[-1]
     shape_kw = dict(stride=stride, padding=padding, groups=groups)
-    prepacked = False
-    if lane_meta is not None:
-        # a baked "lane_packed" layout rides straight onto the fused
-        # kernel when it matches this call; any disagreement (different
-        # groups, an explicit conflicting lane_pack, a non-fused impl, or
-        # an autotune sweep) falls back to unpacking the compact codes to
-        # HWIO — always correct, just without the pre-arranged layout.
-        g_b, cin_lane, meta_groups = lane_meta
-        want = (config or {}).get("lane_pack")
-        usable = (impl == "pallas" and meta_groups == groups
-                  and want in (None, g_b) and not autotune)
-        if usable:
-            prepacked = True
-        else:
-            if (autotune and impl == "pallas" and meta_groups == groups
-                    and want in (None, g_b)):
-                # the sweep still runs, but silently discarding the baked
-                # layout surprises callers expecting the prepacked path
-                _warn_once(
-                    "ops.conv2d: autotune=True unpacked the baked "
-                    "'lane_packed' weight layout for the tuning sweep; the "
-                    "tuned entry applies to the unpacked HWIO path")
-            packed = lane_unpack_codes(packed, hwio, meta_groups, g_b,
-                                       cin_lane)
     if impl == "pallas":
-        explicit = config or {}
-        if autotune and explicit:
-            _warn_once(
-                f"ops.conv2d: autotune=True is a no-op because config= pins "
-                f"{sorted(explicit)}; drop the explicit config to run the "
-                f"tuning sweep for this shape")
-        if autotune and not explicit:
-            config = _autotune.autotune_conv2d(
-                x, packed, qt.scale, qt.cfg, interpret=interp, **shape_kw)
-        elif any(f not in explicit for f in _CONV_CONFIG_FIELDS):
+        config = config or {}
+        if any(f not in config for f in _CONV_CONFIG_FIELDS):
             # the documented contract: fields left unset are filled
             # per-field from the layered autotune table (or heuristics) —
             # a partial config (e.g. only lane_pack) keeps the tuned tiling
@@ -289,23 +236,15 @@ def conv2d(x, qt, *, stride: int = 1, padding="SAME", groups: int = 1,
                 backend=("interpret" if interp else None))
             tuned = _autotune.lookup(key) or _autotune.default_config(
                 B, H, W, C, K, Cout, **shape_kw)
-            config = {**tuned, **explicit}
-        else:
-            config = explicit
-        if prepacked:  # the baked layout forces its own lane_pack factor
-            config = dict(config, lane_pack=lane_meta[0])
+            config = {**tuned, **config}
         g = fused_conv_geometry(B, H, W, C, K, Cout, **shape_kw, **config)
         _obs_metrics.REGISTRY.counter(
             "conv_fold", result="folded" if g["fold"] else "direct").inc()
         _obs_metrics.REGISTRY.counter(
             "conv_row_tiles", layout=row_tile_layout(g)).inc()
         call = lambda: log_conv2d_fused_pallas(x, packed, qt.scale, qt.cfg,
-                                               interpret=interp,
-                                               prepacked=prepacked, **kw,
+                                               interpret=interp, **kw,
                                                **config)
-    elif impl == "pallas_im2col":
-        call = lambda: log_conv2d_pallas(x, packed, qt.scale, qt.cfg,
-                                         interpret=interp, **kw)
     elif impl == "ref":
         call = lambda: log_conv2d_ref(x, packed, qt.scale, qt.cfg, **kw)
     else:
@@ -429,7 +368,7 @@ def _translate_legacy_attn_kwargs(config, legacy: dict):
 
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               scale=None, q_offset=0, k_offset=0, impl: str = "auto",
-              config: AttentionConfig | None = None, autotune: bool = False,
+              config: AttentionConfig | None = None,
               interpret: bool | None = None, block_k=_UNSET,
               acc_dtype=_UNSET, gqa_broadcast=_UNSET):
     """GQA/MQA attention.  q: [B, Tq, H, D]; k, v: [B, Tk, Hkv, D] with H a
@@ -443,8 +382,7 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     them as scalar-prefetch operands.
 
     Block sizes come from ``config=AttentionConfig(...)``; fields left at
-    None are filled from the autotune table (``autotune=True`` measures
-    candidates for this shape first) or heuristics.  ``block_k=`` /
+    None are filled from the autotune table or heuristics.  ``block_k=`` /
     ``acc_dtype=`` / ``gqa_broadcast=`` remain accepted as deprecated
     aliases for one release.
     """
@@ -477,22 +415,12 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
         # pallas (GQA-native; dynamic offsets ride the scalar-prefetch
         # operand)
         bq, bk = config.block_q, config.block_k
-        if autotune and bq is not None and bk is not None:
-            _warn_once(
-                "ops.attention: autotune=True is a no-op because config= "
-                "pins both block_q and block_k; leave one unset to run the "
-                "tuning sweep for this shape")
         if bq is None or bk is None:
-            if autotune:
-                tuned = _autotune.autotune_attention(
-                    q, k, v, causal=causal, window=window, scale=scale,
-                    interpret=interp)
-            else:
-                key = _autotune.attention_key(
-                    B, Tq, Tk, H, Hkv, D, causal=causal, window=window,
-                    backend=("interpret" if interp else None))
-                tuned = _autotune.lookup(key) or \
-                    _autotune.default_attention_config(B, Tq, Tk, H, Hkv, D)
+            key = _autotune.attention_key(
+                B, Tq, Tk, H, Hkv, D, causal=causal, window=window,
+                backend=("interpret" if interp else None))
+            tuned = _autotune.lookup(key) or \
+                _autotune.default_attention_config(B, Tq, Tk, H, Hkv, D)
             bq = bq if bq is not None else tuned["block_q"]
             bk = bk if bk is not None else tuned["block_k"]
         traffic_kw = dict(block_q=bq, block_k=bk)

@@ -8,15 +8,15 @@ These are real, trainable JAX models.  Two orthogonal knobs:
   * ``quant="logq6"`` inserts `fake_log_quant` (straight-through estimator)
     on conv/dense weights and post-ReLU activations — the QAT path, fully
     differentiable.
-  * ``conv_impl="pallas"|"pallas_im2col"|"blockwise"|"ref"|"auto"`` routes
-    every conv through the unified log-domain dispatcher
-    `kernels/ops.conv2d`: weights are packed int8 log codes (once at load
-    via `serving.quantize.quantize_cnn_params`, or on the fly) and the conv
+  * ``conv_impl="pallas"|"blockwise"|"ref"|"auto"`` routes every conv
+    through the unified log-domain dispatcher `kernels/ops.conv2d`:
+    weights are packed int8 HWIO log codes (once at load via
+    `serving.quantize.quantize_cnn_params`, or on the fly) and the conv
     executes against the codes — the true deployed numerics, top tier of
     the three-tier conv stack (fused implicit-im2col Pallas kernel with
-    autotuned block sizes ↔ explicit-im2col fallback ↔ blockwise fallback ↔
-    `core/pe_grid.py` hardware oracle).  Inference-only: packing is not
-    differentiable, so training keeps ``conv_impl=None`` (fake-quant).
+    autotuned block sizes ↔ blockwise fallback ↔ `core/pe_grid.py`
+    hardware oracle).  Inference-only: packing is not differentiable, so
+    training keeps ``conv_impl=None`` (fake-quant).
 
 Layer lists intentionally mirror `core/accelerator.py` so the analytical
 dataflow model and the executable model describe the same networks.
@@ -64,10 +64,9 @@ def conv2d(p, x, *, stride=1, pad="SAME", quant=None, qcfg=LOGQ_DEFAULT,
     """
     w = p["w"]
     if _CONV_SHAPE_TRACE is not None:
-        hwio = tuple(w.shape)  # QuantizedTensor.shape is the logical HWIO
         _CONV_SHAPE_TRACE.append(dict(
             B=int(x.shape[0]), H=int(x.shape[1]), W=int(x.shape[2]),
-            C=int(x.shape[3]), K=int(hwio[0]), Cout=int(hwio[-1]),
+            C=int(x.shape[3]), K=int(w.shape[0]), Cout=int(w.shape[-1]),
             stride=int(stride), padding=pad, groups=int(groups)))
     if conv_impl is not None or isinstance(w, QuantizedTensor):
         qt = w if isinstance(w, QuantizedTensor) else quantize_tensor(w, qcfg)

@@ -58,49 +58,16 @@ def quantize_params(params, qcfg: LogQuantConfig = LogQuantConfig()):
     return jax.tree_util.tree_map_with_path(leaf, params)
 
 
-def quantize_cnn_params(params, qcfg: LogQuantConfig = LogQuantConfig(),
-                        conv_layout: str | None = None):
+def quantize_cnn_params(params, qcfg: LogQuantConfig = LogQuantConfig()):
     """Pack every conv kernel (4-D ``w`` leaf: [K, K, Cin_g, Cout]) of a
     `models/cnn.py` parameter tree into a `QuantizedTensor` — one packing
-    at load time, per-output-channel scales.  Biases and the 2-D dense head
-    stay fp (gathers/heads don't go through the log kernels).
-
-    ``conv_layout="conv_taps"`` additionally pre-reshapes each packed code
-    array to the tap-major ``[K*K, Cin_g, Cout]`` layout the fused Pallas
-    conv kernel streams from HBM, recorded as a layout hint on the
-    `QuantizedTensor` so `ops.conv2d` skips the per-call reshape.
-
-    ``conv_layout="lane_packed"`` goes one further for depthwise kernels:
-    a ``[K, K, 1, Cout]`` leaf must be a ``groups=Cout`` depthwise conv
-    (param trees don't store ``groups`` — any other group count is
-    ambiguous at load time), so its codes are pre-arranged into the
-    128-lane superblock layout ``[n_sb, K*K, g_b*cin_lane, 1]`` the
-    lane-packed kernel streams directly (``layout_meta=(g_b, cin_lane,
-    groups)``).  Non-depthwise leaves fall back to ``conv_taps``; if the
-    call-site ``groups`` disagrees with the baked map, `ops.conv2d`
-    unpacks gracefully."""
-    assert conv_layout in (None, "conv_taps", "lane_packed"), conv_layout
-    from ..kernels.log_conv2d import lane_pack_codes, lane_pack_geometry
+    at load time, per-output-channel scales, codes in the kernel's HWIO
+    layout.  Biases and the 2-D dense head stay fp (gathers/heads don't go
+    through the log kernels)."""
 
     def leaf(path, x):
         if _leaf_name(path) == "w" and getattr(x, "ndim", 0) == 4:
-            qt = quantize_tensor(x, qcfg)
-            K1, K2, cin_g, cout = x.shape
-            if conv_layout == "lane_packed" and cin_g == 1:
-                lp = lane_pack_geometry(cout, cin_g)
-                if lp["g_b"] > 1:
-                    codes = lane_pack_codes(qt.packed, cout, lp["g_b"],
-                                            lp["cin_lane"])
-                    return QuantizedTensor(
-                        codes, jax.numpy.reshape(qt.scale, (-1,)),
-                        qcfg, x.shape, layout="lane_packed",
-                        layout_meta=(lp["g_b"], lp["cin_lane"], cout))
-            if conv_layout in ("conv_taps", "lane_packed"):
-                return QuantizedTensor(
-                    qt.packed.reshape(K1 * K2, cin_g, cout),
-                    jax.numpy.reshape(qt.scale, (1, 1, -1)),
-                    qcfg, x.shape, layout="conv_taps")
-            return qt
+            return quantize_tensor(x, qcfg)
         return x
 
     return jax.tree_util.tree_map_with_path(leaf, params)
@@ -110,21 +77,6 @@ def abstract_quantized_params(params_abs, qcfg: LogQuantConfig =
                               LogQuantConfig()):
     """ShapeDtypeStruct version (dry-run path, no allocation)."""
     return jax.eval_shape(lambda p: quantize_params(p, qcfg), params_abs)
-
-
-def abstract_quantized_cnn_params(params_abs, qcfg: LogQuantConfig =
-                                  LogQuantConfig(),
-                                  conv_layout: str | None = None):
-    """ShapeDtypeStruct version of `quantize_cnn_params` — what the packed
-    tree will look like, without materialising weights.  The cold-start
-    benchmark and the autotune warm-start tooling trace quantized CNN
-    dispatch through this path: layouts (``conv_taps``/``lane_packed``)
-    resolve from shapes alone, and `ops.conv2d`'s autotune keys only
-    depend on shapes + `qcfg`, so abstract packing exercises the exact
-    table lookups real serving performs."""
-    return jax.eval_shape(
-        lambda p: quantize_cnn_params(p, qcfg, conv_layout=conv_layout),
-        params_abs)
 
 
 def quantized_fraction(params) -> float:

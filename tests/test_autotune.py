@@ -5,7 +5,6 @@ invalidation, candidate filtering, and numerics of tuned configs."""
 
 import json
 import os
-import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -268,60 +267,6 @@ def test_partial_conv_config_fills_from_table(monkeypatch):
     assert seen["block_cin"] == 8
 
 
-# --------------------------------------------- suppressed-autotune warnings
-
-
-@pytest.fixture()
-def _fresh_warnings(monkeypatch):
-    monkeypatch.setattr(ops, "_WARNED_ONCE", set())
-
-
-def test_autotune_suppressed_by_conv_config_warns_once(_fresh_warnings):
-    rng = np.random.default_rng(4)
-    x = jnp.asarray(rng.normal(size=(1, 8, 8, 5)).astype(np.float32))
-    qt = quantize_tensor(jnp.asarray(
-        rng.normal(size=(3, 3, 5, 7)).astype(np.float32)))
-    cfg = dict(block_cin=8, block_cout=8, rows_per_tile=4,
-               batch_per_tile=1, lane_pack=1)
-    with pytest.warns(UserWarning, match="autotune=True is a no-op"):
-        ops.conv2d(x, qt, impl="pallas", interpret=True, config=cfg,
-                   autotune=True)
-    assert not autotune._load()["entries"]      # and no sweep ran
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # one-shot: second call quiet
-        ops.conv2d(x, qt, impl="pallas", interpret=True, config=cfg,
-                   autotune=True)
-
-
-def test_autotune_suppressed_by_attention_config_warns(_fresh_warnings):
-    rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.normal(size=(1, 8, 2, 8)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 8, 2, 8)), jnp.float32)
-    with pytest.warns(UserWarning, match="autotune=True is a no-op"):
-        ops.attention(q, k, k, impl="pallas", interpret=True, autotune=True,
-                      config=ops.AttentionConfig(block_q=8, block_k=8))
-    assert not autotune._load()["entries"]      # and no sweep ran
-
-
-def test_autotune_unpacks_baked_lane_layout_with_warning(_fresh_warnings):
-    from repro.serving.quantize import quantize_cnn_params
-    rng = np.random.default_rng(6)
-    C = 4
-    x = jnp.asarray(rng.normal(size=(1, 4, 4, C)).astype(np.float32))
-    params = {"w": jnp.asarray(rng.normal(size=(3, 3, 1, C))
-                               .astype(np.float32))}
-    qp = quantize_cnn_params(params, conv_layout="lane_packed")
-    assert qp["w"].layout == "lane_packed"
-    with pytest.warns(UserWarning, match="unpacked the baked"):
-        y = ops.conv2d(x, qp["w"], impl="pallas", interpret=True,
-                       groups=C, autotune=True)
-    y_ref = ops.conv2d(x, qp["w"], impl="blockwise", groups=C)
-    np.testing.assert_allclose(
-        np.asarray(y), np.asarray(y_ref),
-        atol=1e-4 * float(jnp.max(jnp.abs(y_ref)) + 1))
-    assert autotune._load()["entries"]          # the sweep did run
-
-
 def test_candidates_fit_vmem_budget_and_dedupe():
     cands = autotune.candidate_configs(*ARGS)
     assert cands, "no candidates for a tiny layer"
@@ -407,13 +352,21 @@ def test_autotune_persists_winner_and_matches_ref():
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 5)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(3, 3, 5, 7)).astype(np.float32))
     qt = quantize_tensor(w)
-    y_tuned = ops.conv2d(x, qt, impl="pallas", interpret=True, autotune=True)
-    y_ref = ops.conv2d(x, qt, impl="ref")
-    np.testing.assert_allclose(np.asarray(y_tuned), np.asarray(y_ref),
-                               atol=1e-4 * float(jnp.max(jnp.abs(y_ref)) + 1))
+    winner = autotune.autotune_conv2d(x, qt.packed, qt.scale, qt.cfg,
+                                      interpret=True, reps=1)
     key = autotune.conv_key(*ARGS, cfg=qt.cfg, backend="interpret")
-    winner = autotune.lookup(key)
-    assert winner is not None
-    # subsequent plain calls pick the persisted winner up transparently
-    y_again = ops.conv2d(x, qt, impl="pallas", interpret=True)
-    np.testing.assert_array_equal(np.asarray(y_again), np.asarray(y_tuned))
+    assert autotune.lookup(key) == winner
+    # subsequent plain calls pick the persisted winner up: a user-tier
+    # hit, the same launch as the winner's config pinned, and the same
+    # conv as the oracle
+    hits = obs_metrics.REGISTRY.counter("autotune_lookup", op="conv2d",
+                                        result="hit_user")
+    before = hits.value
+    y = ops.conv2d(x, qt, impl="pallas", interpret=True)
+    assert hits.value == before + 1
+    y_pinned = ops.conv2d(x, qt, impl="pallas", interpret=True,
+                          config=winner)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_pinned))
+    y_ref = ops.conv2d(x, qt, impl="ref")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=1e-4 * float(jnp.max(jnp.abs(y_ref)) + 1))

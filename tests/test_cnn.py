@@ -73,39 +73,26 @@ def test_cnn_packed_at_load_matches_on_the_fly():
                                   np.asarray(apply_bw(params, x)))
 
 
-def test_cnn_conv_taps_layout_matches_hwio():
-    """conv_layout="conv_taps" pre-reshapes packed codes to the fused
-    kernel's tap-major HBM layout at load time — same numerics, and
-    dequantize restores the original [K, K, Cin_g, Cout] kernel."""
-    from repro.serving.quantize import quantize_cnn_params
-    key = jax.random.PRNGKey(10)
-    params, apply_bw = make_cnn("mobilenet_v1", key, n_classes=10,
-                                width_mult=0.25, quant="logq6",
-                                conv_impl="blockwise")
-    q_hwio = quantize_cnn_params(params)
-    q_taps = quantize_cnn_params(params, conv_layout="conv_taps")
-    stem = q_taps["stem"]["w"]
-    assert stem.layout == "conv_taps" and stem.packed.ndim == 3
-    np.testing.assert_array_equal(
-        np.asarray(stem.dequantize(jnp.float32)),
-        np.asarray(q_hwio["stem"]["w"].dequantize(jnp.float32)))
-    x = jax.random.normal(jax.random.PRNGKey(11), (2, 32, 32, 3))
-    np.testing.assert_array_equal(np.asarray(apply_bw(q_taps, x)),
-                                  np.asarray(apply_bw(q_hwio, x)))
+@pytest.mark.parametrize("name", sorted(CNNS))
+def test_cnn_conv_impl_fused_pallas_matches_blockwise(name):
+    """The served weights, made and packed at load in one jitted call as
+    the benchmark makes them (`quantize_cnn_params`: HWIO codes), through
+    the model zoo's conv_impl="pallas" route onto the fused
+    implicit-im2col kernel (interpret mode on CPU): the jitted forward's
+    logits match the blockwise lowering of the same packed tree."""
+    applies = {}
 
+    def served_weights(key):
+        for impl in ("blockwise", "pallas"):
+            params, applies[impl] = make_cnn(
+                name, key, n_classes=10, width_mult=0.25, quant="logq6",
+                conv_impl=impl, interpret=True)
+        return quantize_cnn_params(params)
 
-def test_cnn_conv_impl_fused_pallas_matches_blockwise():
-    """The model zoo's conv_impl="pallas" routes through the fused
-    implicit-im2col kernel (interpret mode on CPU) — logits match the
-    blockwise lowering."""
-    key = jax.random.PRNGKey(12)
-    params, apply_bw = make_cnn("vgg16", key, n_classes=10, width_mult=0.25,
-                                quant="logq6", conv_impl="blockwise")
-    _, apply_fz = make_cnn("vgg16", key, n_classes=10, width_mult=0.25,
-                           quant="logq6", conv_impl="pallas", interpret=True)
+    qparams = jax.jit(served_weights)(jax.random.PRNGKey(12))
     x = jax.random.normal(jax.random.PRNGKey(13), (1, 16, 16, 3))
-    lb = np.asarray(apply_bw(params, x))
-    lz = np.asarray(apply_fz(params, x))
+    lb = np.asarray(jax.jit(applies["blockwise"])(qparams, x))
+    lz = np.asarray(jax.jit(applies["pallas"])(qparams, x))
     np.testing.assert_allclose(lz, lb, atol=1e-3 * (np.abs(lb).max() + 1))
 
 

@@ -52,11 +52,10 @@ def test_conv2d_impls_agree(B, H, W, C, K, P, stride, padding, groups):
     kw = dict(stride=stride, padding=padding, groups=groups)
     y_ref = ops.conv2d(x, qt, impl="ref", **kw)
     y_bw = ops.conv2d(x, qt, impl="blockwise", **kw)
-    y_im = ops.conv2d(x, qt, impl="pallas_im2col", interpret=True, **kw)
     y_fz = ops.conv2d(x, qt, impl="pallas", interpret=True, **kw)
-    assert y_ref.shape == y_bw.shape == y_im.shape == y_fz.shape
+    assert y_ref.shape == y_bw.shape == y_fz.shape
     tol = 1e-4 * float(jnp.max(jnp.abs(y_ref)) + 1)
-    for y in (y_bw, y_im, y_fz):
+    for y in (y_bw, y_fz):
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=tol)
     # acceptance: fused ≡ blockwise within 1e-3 max-abs
     assert float(jnp.max(jnp.abs(y_fz - y_bw))) < 1e-3
@@ -276,6 +275,10 @@ LANE_SHAPES = [  # B, H, W, C, K, P, stride, padding, groups
     (1, 8, 8, 8, 3, 8, 1, "VALID", 4),     # cin_g=2
     (2, 8, 8, 16, 5, 8, 2, 2, 4),          # cin_g=4, K=5, int padding
     (1, 8, 8, 4, 3, 8, 1, ((1, 2), (0, 1)), 4),  # asymmetric pads, depthwise
+    # more than one 128-lane superblock, as MobileNet v1's 256-1024-channel
+    # depthwise convs have (n_sb 2-8):
+    (1, 8, 8, 160, 3, 160, 1, "SAME", 160),  # 2 superblocks, padded groups
+    (1, 9, 9, 256, 3, 256, 2, "SAME", 256),  # 2 full superblocks, stride 2
 ]
 
 
@@ -298,7 +301,8 @@ def test_lane_packed_agrees_with_padded_and_lax(B, H, W, C, K, P, stride,
                           config=dict(lane_pack=1), **kw)
     # the packing must actually engage for these narrow-group shapes
     from repro.kernels.log_conv2d import lane_pack_geometry
-    assert lane_pack_geometry(groups, C // groups)["g_b"] > 1
+    lp = lane_pack_geometry(groups, C // groups)
+    assert lp["g_b"] > 1 and lp["n_sb"] == -(-groups // lp["g_b"])
     eps = 16 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(y_padded)) + 1)
     np.testing.assert_allclose(np.asarray(y_packed), np.asarray(y_padded),
                                atol=eps)
@@ -311,61 +315,31 @@ def test_lane_packed_agrees_with_padded_and_lax(B, H, W, C, K, P, stride,
 
 
 def test_lane_pack_codes_roundtrip_exact():
-    """pack → unpack is the identity on the raw int8 codes."""
-    from repro.kernels.log_conv2d import (lane_pack_codes, lane_pack_geometry,
-                                          lane_unpack_codes)
+    """`lane_pack_codes` is its index map, element by element: lane
+    ``g·cin_lane + i`` of superblock ``sb`` holds group ``sb·g_b + g``'s
+    channel ``i``, and every padding lane (``i ≥ cin_g`` or a group past
+    the last) holds code 0, the dedicated zero code."""
+    from repro.kernels.log_conv2d import lane_pack_codes, lane_pack_geometry
     rng = np.random.default_rng(6)
-    for C, groups, P, K in ((6, 6, 6, 3), (12, 4, 8, 3), (16, 4, 8, 5)):
-        cin_g = C // groups
+    for C, groups, P, K in ((6, 6, 6, 3), (12, 4, 8, 3), (16, 4, 8, 5),
+                            (160, 160, 160, 3)):
+        cin_g, cout_g = C // groups, P // groups
         w = jnp.asarray(rng.normal(size=(K, K, cin_g, P)).astype(np.float32))
         qt = quantize_tensor(w)
         lp = lane_pack_geometry(groups, cin_g)
-        codes = lane_pack_codes(qt.packed, groups, lp["g_b"], lp["cin_lane"])
-        assert codes.shape == (lp["n_sb"], K * K,
-                               lp["g_b"] * lp["cin_lane"], P // groups)
-        back = lane_unpack_codes(codes, qt.packed.shape, groups, lp["g_b"],
-                                 lp["cin_lane"])
-        np.testing.assert_array_equal(np.asarray(back), np.asarray(qt.packed))
-
-
-def test_lane_packed_quantized_tensor_serving_path():
-    """`quantize_cnn_params(conv_layout="lane_packed")` bakes depthwise
-    kernels into the superblock layout; `ops.conv2d` rides it prepacked
-    (bit-identical to packing on the fly) and unpacks gracefully when the
-    call disagrees with the baked map."""
-    from repro.serving.quantize import quantize_cnn_params
-    rng = np.random.default_rng(7)
-    C = 12
-    w = jnp.asarray(rng.normal(size=(3, 3, 1, C)).astype(np.float32))
-    x = jnp.asarray(rng.normal(size=(1, 8, 8, C)).astype(np.float32))
-    params = {"conv": {"w": w, "b": jnp.zeros(C)}}
-    qp = quantize_cnn_params(params, conv_layout="lane_packed")
-    qt_lp = qp["conv"]["w"]
-    assert qt_lp.layout == "lane_packed"
-    g_b, cin_lane, meta_groups = qt_lp.layout_meta
-    assert meta_groups == C and g_b > 1
-    # dequantize round-trips through the packed layout exactly
-    qt = quantize_tensor(w)
-    np.testing.assert_array_equal(np.asarray(qt_lp.dequantize(jnp.float32)),
-                                  np.asarray(qt.dequantize(jnp.float32)))
-    # prepacked fast path ≡ on-the-fly packing, bit for bit
-    y_fly = ops.conv2d(x, qt, impl="pallas", interpret=True, groups=C)
-    y_pre = ops.conv2d(x, qt_lp, impl="pallas", interpret=True, groups=C)
-    np.testing.assert_array_equal(np.asarray(y_pre), np.asarray(y_fly))
-    # graceful unpack: non-pallas impl and a conflicting explicit lane_pack
-    y_bw = ops.conv2d(x, qt, impl="blockwise", groups=C)
-    np.testing.assert_array_equal(
-        np.asarray(ops.conv2d(x, qt_lp, impl="blockwise", groups=C)),
-        np.asarray(y_bw))
-    y_off = ops.conv2d(x, qt_lp, impl="pallas", interpret=True, groups=C,
-                       config=ops.ConvConfig(lane_pack=1))
-    tol = 1e-4 * float(jnp.max(jnp.abs(y_bw)) + 1)
-    np.testing.assert_allclose(np.asarray(y_off), np.asarray(y_bw), atol=tol)
-    # non-depthwise leaves fall back to conv_taps
-    qp2 = quantize_cnn_params({"c": {"w": jnp.asarray(
-        rng.normal(size=(3, 3, 4, 8)).astype(np.float32))}},
-        conv_layout="lane_packed")
-    assert qp2["c"]["w"].layout == "conv_taps"
+        g_b, cin_lane, n_sb = lp["g_b"], lp["cin_lane"], lp["n_sb"]
+        codes = np.asarray(lane_pack_codes(qt.packed, groups, g_b, cin_lane))
+        assert codes.shape == (n_sb, K * K, g_b * cin_lane, cout_g)
+        hwio = np.asarray(qt.packed).reshape(K * K, cin_g, groups, cout_g)
+        for sb in range(n_sb):
+            for g in range(g_b):
+                for i in range(cin_lane):
+                    lane = codes[sb, :, g * cin_lane + i, :]  # [taps, cout_g]
+                    grp = sb * g_b + g
+                    if i < cin_g and grp < groups:
+                        np.testing.assert_array_equal(lane, hwio[:, i, grp])
+                    else:
+                        assert not lane.any(), (sb, g, i)
 
 
 def test_lane_pack_autotune_candidates_and_traffic():
@@ -432,8 +406,7 @@ def test_kernel_matches_pe_grid_3x3(stride):
 
     qt = quantize_tensor(jnp.asarray(w), CFG)
     xd = jnp.asarray(_deq(x))[None]  # the codes the grid's threads see
-    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True}),
-                    ("pallas_im2col", {"interpret": True})):
+    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True})):
         y_k = ops.conv2d(xd, qt, stride=stride, padding="VALID", impl=impl,
                          **kw)
         np.testing.assert_allclose(np.asarray(y_k[0]), y_grid,
@@ -451,8 +424,7 @@ def test_kernel_matches_pe_grid_depthwise():
 
     qt = quantize_tensor(jnp.asarray(w)[:, :, None, :], CFG)  # [3,3,1,C]
     xd = jnp.asarray(_deq(x))[None]
-    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True}),
-                    ("pallas_im2col", {"interpret": True})):
+    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True})):
         y_k = ops.conv2d(xd, qt, padding="VALID", groups=C, impl=impl, **kw)
         np.testing.assert_allclose(np.asarray(y_k[0]), y_grid,
                                    atol=_grid_tol(y_grid))
@@ -468,8 +440,7 @@ def test_kernel_matches_pe_grid_1x1():
 
     qt = quantize_tensor(jnp.asarray(w)[None, None], CFG)  # [1,1,20,6]
     xd = jnp.asarray(_deq(x))[None]
-    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True}),
-                    ("pallas_im2col", {"interpret": True})):
+    for impl, kw in (("blockwise", {}), ("pallas", {"interpret": True})):
         y_k = ops.conv2d(xd, qt, padding="VALID", impl=impl, **kw)
         np.testing.assert_allclose(np.asarray(y_k[0]), y_grid,
                                    atol=_grid_tol(y_grid))

@@ -135,13 +135,13 @@ def test_resolve_impl_precedence():
     assert resolve_impl("attention") == ("blockwise", True)
     assert resolve_impl("attention", "pallas") == ("pallas", True)
     assert resolve_impl("attention", "pallas", False) == ("pallas", False)
-    assert resolve_impl("conv2d", "pallas_im2col")[0] == "pallas_im2col"
     for op in ("log_matmul", "conv2d", "attention", "wkv6"):
         with pytest.raises(ValueError, match="unknown"):
             resolve_impl(op, "nope")
-    # ops without an im2col variant reject conv-only aliases
-    with pytest.raises(ValueError):
-        resolve_impl("attention", "pallas_im2col")
+    # no op takes an explicit-im2col impl
+    for op in ("conv2d", "attention"):
+        with pytest.raises(ValueError, match="unknown"):
+            resolve_impl(op, "pallas_im2col")
 
 
 # --------------------------------------------------------------------------
